@@ -213,6 +213,18 @@ def bench_nsga2_dominance(reduced=False):
         f"{passes}_pairwise_passes")
 
 
+def _require_cpu_host(bench: str) -> None:
+    """The forced-host-device scaling rows time the CPU in child processes
+    that the parent starts after it has touched JAX: on a chip host the
+    rows would carry CPU times under the chip's backend header, and a
+    child that reached for the chip would find it held by this process."""
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{bench} models multi-device scaling on forced CPU host "
+            f"devices; refusing to run on backend "
+            f"{jax.default_backend()!r}")
+
+
 def bench_island_scaling(reduced=False):
     """Device-resident epoch scaling vs simulated device count (ROADMAP's
     EGI scale-out story): one subprocess per forced host device count (the
@@ -225,6 +237,7 @@ def bench_island_scaling(reduced=False):
     turns and ONE real device's critical path is wall/k — the derived
     simulated speedup is t1 / (tk / k), honest about the model
     (docs/performance.md)."""
+    _require_cpu_host("bench_island_scaling")
     shape = "reduced" if reduced else "full"
     counts = (1, 2) if reduced else (1, 2, 4, 8)
     child = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -405,6 +418,7 @@ def bench_egi_device_scaling(reduced=False):
     real device's critical path is wall/k — the derived simulated speedup
     is t1 / (tk / k), the same honest model as island_scaling
     (docs/performance.md)."""
+    _require_cpu_host("bench_egi_device_scaling")
     shape = "reduced" if reduced else "full"
     counts = (1, 2) if reduced else (1, 2, 4)
     n_total = 4096 if reduced else 200_000
@@ -890,6 +904,8 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default="",
                     help="also write machine-readable results to this path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     for bench in BENCHES:

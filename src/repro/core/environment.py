@@ -24,7 +24,8 @@ import jax
 import numpy as np
 
 from repro.core.faults import (FaultSpec, InjectedFailure, ResultCorruption,
-                               corrupt_output, interruptible_sleep)
+                               corrupt_output, interruptible_sleep,
+                               is_device_error)
 from repro.core.prototype import Context
 from repro.core.task import Task, TaskError
 from repro.runtime import sharding as shd
@@ -336,6 +337,8 @@ class Environment:
             except Exception as e:
                 err = e
             self._note_attempt(meta, self.attempt_outcome(err), a_t0, err)
+            if is_device_error(err):
+                raise err                  # a retry would repeat it
             with self._lock:
                 self.stats.retried += 1
             if meta is not None:
@@ -385,6 +388,8 @@ class Environment:
                     other.cancel()
                 return result
             except Exception as e:
+                if is_device_error(e):
+                    raise
                 err = e
         raise RuntimeError(f"all speculative copies of {task.name} failed") \
             from err
@@ -422,6 +427,30 @@ class LocalEnvironment(Environment):
     pass
 
 
+def _stack_lanes(contexts: Sequence[Context]) -> Optional[Dict[str, Any]]:
+    """Stack the contexts' leaves into leading-axis lane arrays, or None
+    when they are ragged: different keys, or a leaf whose shape or dtype
+    differs across contexts or that is not numeric. Only ragged fan-outs
+    leave the device path; any error past this point is the program's and
+    surfaces to the caller."""
+    names = sorted(contexts[0].keys())
+    if any(sorted(c.keys()) != names for c in contexts):
+        return None
+    batched = {}
+    for n in names:
+        leaves = [v if isinstance(v, jax.Array) else np.asarray(v)
+                  for v in (c[n] for c in contexts)]
+        first = leaves[0]
+        if (not isinstance(first, jax.Array)
+                and first.dtype.kind not in "biufc") or any(
+                (a.shape, a.dtype) != (first.shape, first.dtype)
+                for a in leaves):
+            return None
+        stack = jax.numpy.stack if isinstance(first, jax.Array) else np.stack
+        batched[n] = stack(leaves)
+    return batched
+
+
 class MeshEnvironment(Environment):
     """Delegates JaxTasks to a device mesh; explorations become batched
     lanes sharded over the data axes (one grid job per lane)."""
@@ -452,17 +481,9 @@ class MeshEnvironment(Environment):
         vmap the task function, shard the lane axis over data/pod axes."""
         if task.kind != "jax" or not contexts:
             return super().map_explore(task, contexts)
-        names = sorted(contexts[0].keys())
-        for c in contexts:
-            if sorted(c.keys()) != names:
-                return super().map_explore(task, contexts)  # ragged -> host
-        batched = {}
-        try:
-            for n in names:
-                batched[n] = jax.numpy.stack(
-                    [jax.numpy.asarray(c[n]) for c in contexts])
-        except Exception:
-            return super().map_explore(task, contexts)
+        batched = _stack_lanes(contexts)
+        if batched is None:
+            return super().map_explore(task, contexts)  # ragged -> host
 
         def one(ctx):
             return task.fn(Context(ctx))
@@ -546,7 +567,7 @@ class DeviceEnvironment(Environment):
                     job: Optional[str] = None,
                     wake: Optional[threading.Event] = None
                     ) -> Tuple[Context, Optional[str]]:
-        # jax.default_device is thread-local (verified under jax 0.4.37),
+        # jax.default_device is thread-local (verified under jax 0.9.0),
         # so concurrent attempts on other members cannot unpin this one.
         with jax.default_device(self._next_device()):
             return super().run_attempt(task, context, attempt=attempt,
@@ -565,15 +586,9 @@ class DeviceEnvironment(Environment):
         """Batched lanes explicitly placed on the member's own devices."""
         if task.kind != "jax" or not contexts:
             return super().map_explore(task, contexts)
-        names = sorted(contexts[0].keys())
-        for c in contexts:
-            if sorted(c.keys()) != names:
-                return super().map_explore(task, contexts)  # ragged -> host
-        try:
-            batched = {n: np.stack([np.asarray(c[n]) for c in contexts])
-                       for n in names}
-        except Exception:
-            return super().map_explore(task, contexts)
+        batched = _stack_lanes(contexts)
+        if batched is None:
+            return super().map_explore(task, contexts)  # ragged -> host
 
         n_lanes = len(contexts)
         devs = self.devices

@@ -11,7 +11,9 @@ whatever environments are attached. This module is that layer:
   slots), ``latency_s``, ``timeout_s``, and injectable ``FaultSpec``.
 - **Resubmission**: a failed / hung / corrupted attempt is resubmitted with
   exponential backoff to another member (the failing member is deprioritized
-  for that job), up to ``retries`` total resubmissions.
+  for that job), up to ``retries`` total resubmissions. A device error
+  (``faults.is_device_error``: the program failed to compile, lower or
+  run) is raised at once instead — resubmitting it would repeat it.
 - **Oversubmission / speculation**: ``speculative=k`` dispatches duplicate
   attempts of one job to ``k`` distinct members simultaneously; the first
   verified result wins and the losers are cancelled (EGI's over-submission
@@ -42,7 +44,7 @@ import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from repro.core.environment import Environment
-from repro.core.faults import interruptible_sleep
+from repro.core.faults import interruptible_sleep, is_device_error
 from repro.core.prototype import Context
 from repro.core.task import Task, TaskError
 
@@ -224,6 +226,9 @@ class EnvironmentPool:
                 self.stats.inc(failed=1, in_flight=-1)
                 raise                    # declaration bugs never resubmit
             except Exception as e:
+                if is_device_error(e):   # a resubmission would repeat it
+                    self.stats.inc(failed=1, in_flight=-1)
+                    raise
                 err = e
                 exclude.update(m.name for m in picked)
                 if len(exclude) >= len(self.members):
@@ -262,6 +267,8 @@ class EnvironmentPool:
             try:
                 result = f.result()
             except Exception as e:
+                if is_device_error(e):
+                    raise
                 err = e
                 continue
             self.stats.inc(speculative_wins=1)
@@ -417,16 +424,17 @@ class EnvironmentPool:
                     outs = [self._attempt_on(m, task, c, lane_attempts[idx],
                                              {"attempts": []}) for c in ctxs]
                 ok = True
-            except TaskError as e:
-                with cond:
-                    # lane_running gates speculative duplication
-                    # (lane_running[i] < self.speculative): every exit path
-                    # must undo the worker's increment or the slot leaks.
-                    lane_running[idx] -= 1
-                    fatal.append(e)
-                    cond.notify_all()
-                return
             except Exception as e:
+                if isinstance(e, TaskError) or is_device_error(e):
+                    with cond:
+                        # lane_running gates speculative duplication
+                        # (lane_running[i] < self.speculative): every exit
+                        # path must undo the worker's increment or the slot
+                        # leaks.
+                        lane_running[idx] -= 1
+                        fatal.append(e)
+                        cond.notify_all()
+                    return
                 ok = False
                 lane_err[idx] = e
             wall = time.monotonic() - t0

@@ -46,6 +46,22 @@ class ResultCorruption(RuntimeError):
     transit. Transient from the submitter's point of view — resubmit."""
 
 
+def is_device_error(err: BaseException) -> bool:
+    """True when the compiled program itself failed: the compiler or the
+    device runtime raised (``jax.errors.JaxRuntimeError``), MLIR or Pallas
+    lowering refused the program, or an error was raised while JAX lowered
+    it (JAX chains those to a ``JaxStackTraceBeforeTransformation``).
+    Another attempt would fail the same way, so the environment layer
+    raises these at once instead of retrying them like the transient
+    faults above; retrying would only disguise a broken program as a slow
+    or flaky one."""
+    import jax
+    return (isinstance(err, jax.errors.JaxRuntimeError)
+            or type(err).__name__ in ("MLIRError", "LoweringException")
+            or type(err.__cause__).__name__
+            == "JaxStackTraceBeforeTransformation")
+
+
 def _unit(seed: int, kind: str, job: str, attempt: int) -> float:
     """Deterministic uniform draw in [0, 1) for one fault decision."""
     h = hashlib.sha256(f"{seed}|{kind}|{job}|{attempt}".encode()).digest()

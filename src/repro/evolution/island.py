@@ -119,6 +119,26 @@ def init_island_state(cfg: NSGA2Config, key, *, n_islands: int,
     return place_island_state(state)
 
 
+def _per_island(fn: Callable) -> Callable:
+    """``fn`` (pytree with a leading island axis -> same) run on each
+    device's own islands. Under a multi-device mesh it becomes a
+    ``shard_map`` over the island mesh axes: island-local work needs no
+    communication, and the TPU compiler cannot partition a Pallas kernel
+    by itself. An island count the axes do not divide runs replicated on
+    every device."""
+
+    def run(tree):
+        mesh = active_mesh()
+        if mesh is None or mesh.size == 1:
+            return fn(tree)
+        n_i = jax.tree.leaves(tree)[0].shape[0]
+        spec = logical_to_spec(("island",), (n_i,), mesh)
+        return jax.shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
+                             check_vma=False)(tree)
+
+    return run
+
+
 # ---------------------------------------------------------------------------
 # Epoch stages
 # ---------------------------------------------------------------------------
@@ -144,7 +164,7 @@ def make_evolve(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
 
     def evolve(islands: ga.GAState) -> ga.GAState:
         islands = _constrain_islands(islands)
-        islands = jax.vmap(evolve_island)(islands)
+        islands = _per_island(jax.vmap(evolve_island))(islands)
         return _constrain_islands(islands)
 
     return evolve
@@ -164,18 +184,24 @@ def make_merge(cfg: NSGA2Config, *, merge_top_k: int = 0) -> Callable:
     ONE grouped single-pass dominance launch instead of a vmapped launch per
     island pool."""
 
+    def top_k(pop):
+        """(objectives, valid) of n islands -> (n, merge_top_k) indices of
+        each island's best, ranked in one grouped launch."""
+        obj, valid = pop
+        n, mu = valid.shape
+        flat_o = obj.reshape(n * mu, -1)
+        flat_v = valid.reshape(n * mu)
+        groups = jnp.repeat(jnp.arange(n, dtype=jnp.int32), mu)
+        ranks = nsga2.nondominated_ranks(flat_o, flat_v, groups=groups)
+        crowd = nsga2.crowding_distance(flat_o, ranks, groups=groups,
+                                        n_groups=n)
+        key_val = nsga2.truncation_key(ranks, crowd, flat_v)
+        return jnp.argsort(key_val.reshape(n, mu), axis=1)[:, :merge_top_k]
+
     def merge_islands(archive: Archive, islands: ga.GAState) -> Archive:
         n_i, mu = islands.genomes.shape[:2]
         if merge_top_k and merge_top_k < mu:
-            flat_o = islands.objectives.reshape(n_i * mu, -1)
-            flat_v = islands.valid.reshape(n_i * mu)
-            groups = jnp.repeat(jnp.arange(n_i, dtype=jnp.int32), mu)
-            ranks = nsga2.nondominated_ranks(flat_o, flat_v, groups=groups)
-            crowd = nsga2.crowding_distance(flat_o, ranks, groups=groups,
-                                            n_groups=n_i)
-            key_val = nsga2.truncation_key(ranks, crowd, flat_v)
-            idx = jnp.argsort(key_val.reshape(n_i, mu),
-                              axis=1)[:, :merge_top_k]
+            idx = _per_island(top_k)((islands.objectives, islands.valid))
             sel_g = jnp.take_along_axis(islands.genomes, idx[..., None],
                                         axis=1)
             sel_o = jnp.take_along_axis(islands.objectives, idx[..., None],

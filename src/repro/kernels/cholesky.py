@@ -52,13 +52,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 
-# jax <= 0.4.x names it TPUCompilerParams; >= 0.5 CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
+VMEM_LIMIT = 32 * 2 ** 20
 
 
 # ---------------------------------------------------------------------------
@@ -79,7 +73,7 @@ def _gp_diag_kernel(x_ref, l_ref, linv_ref, *, n, kind, lengthscale, nugget):
 
 
 def _panel_kernel(a_ref, linv_ref, o_ref):
-    o_ref[...] = jnp.dot(a_ref[...], linv_ref[...].T)
+    o_ref[...] = ref.tile_dot(a_ref[...], linv_ref[...].T)
 
 
 def _gp_panel_kernel(xi_ref, x0_ref, linv_ref, o_ref, *, block, n, kind,
@@ -87,14 +81,14 @@ def _gp_panel_kernel(xi_ref, x0_ref, linv_ref, o_ref, *, block, n, kind,
     row0 = (pl.program_id(0) + 1) * block
     a = ref.gp_tile_ref(xi_ref[...], x0_ref[...], row0, 0, n, kind=kind,
                         lengthscale=lengthscale, nugget=nugget)
-    o_ref[...] = jnp.dot(a, linv_ref[...].T)
+    o_ref[...] = ref.tile_dot(a, linv_ref[...].T)
 
 
 def _trailing_kernel(a_ref, pi_ref, pj_ref, o_ref):
     i, j = pl.program_id(0), pl.program_id(1)
     a = a_ref[...]
-    o_ref[...] = jnp.where(j <= i, a - jnp.dot(pi_ref[...], pj_ref[...].T),
-                           a)
+    o_ref[...] = jnp.where(
+        j <= i, a - ref.tile_dot(pi_ref[...], pj_ref[...].T), a)
 
 
 def _gp_trailing_kernel(xi_ref, xj_ref, pi_ref, pj_ref, o_ref, *, block, n,
@@ -103,8 +97,8 @@ def _gp_trailing_kernel(xi_ref, xj_ref, pi_ref, pj_ref, o_ref, *, block, n,
     a = ref.gp_tile_ref(xi_ref[...], xj_ref[...], (i + 1) * block,
                         (j + 1) * block, n, kind=kind,
                         lengthscale=lengthscale, nugget=nugget)
-    o_ref[...] = jnp.where(j <= i, a - jnp.dot(pi_ref[...], pj_ref[...].T),
-                           a)
+    o_ref[...] = jnp.where(
+        j <= i, a - ref.tile_dot(pi_ref[...], pj_ref[...].T), a)
 
 
 def _call(kernel, grid, in_specs, out_specs, out_shape, args, interpret,
@@ -112,7 +106,8 @@ def _call(kernel, grid, in_specs, out_specs, out_shape, args, interpret,
     return pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, scratch_shapes=list(scratch_shapes),
-        compiler_params=_CompilerParams(dimension_semantics=semantics),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret)(*args)
 
 
@@ -260,9 +255,9 @@ def _solve_fwd_kernel(l_ref, linv_ref, b_ref, o_ref, x_scr, *, nb, block):
     acc = b_ref[...]
     for j in range(nb):
         lij = l_ref[:, j * block:(j + 1) * block]
-        d = jnp.dot(lij, x_scr[j])
+        d = ref.tile_dot(lij, x_scr[j])
         acc = acc - jnp.where(j < i, d, jnp.zeros_like(d))
-    xi = jnp.dot(linv_ref[0], acc)
+    xi = ref.tile_dot(linv_ref[0], acc)
     x_scr[i] = xi
     o_ref[...] = xi
 
@@ -272,9 +267,9 @@ def _solve_bwd_kernel(l_ref, linv_ref, b_ref, o_ref, x_scr, *, nb, block):
     acc = b_ref[...]
     for j in range(nb):
         ljr = l_ref[j * block:(j + 1) * block, :]
-        d = jnp.dot(ljr.T, x_scr[j])
+        d = ref.tile_dot(ljr.T, x_scr[j])
         acc = acc - jnp.where(j > r, d, jnp.zeros_like(d))
-    xr = jnp.dot(linv_ref[0].T, acc)
+    xr = ref.tile_dot(linv_ref[0].T, acc)
     x_scr[r] = xr
     o_ref[...] = xr
 
@@ -300,7 +295,8 @@ def tri_solve_blocked(l, b, *, trans=False, block=256, rhs_block=256,
         in_specs=[pl.BlockSpec((bs, bs), lambda i: (i, i))],
         out_specs=pl.BlockSpec((1, bs, bs), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, bs, bs), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret)(l)
 
     if not trans:
@@ -322,6 +318,6 @@ def tri_solve_blocked(l, b, *, trans=False, block=256, rhs_block=256,
         out_specs=b_spec,
         out_shape=jax.ShapeDtypeStruct((n_p, m_p), jnp.float32),
         scratch_shapes=[pltpu.VMEM((nb, bs, rhs_block), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret)(l, linvs, b)

@@ -15,43 +15,42 @@ Two entry points share one tiling scheme:
     Front peeling then becomes popcount decrements over the bitmap instead of
     one full pairwise pass per front (see evolution/nsga2.nondominated_ranks).
 
-Grid = (num_i_blocks, num_j_blocks), j innermost/sequential; the per-i-block
-i32 counter lives in VMEM scratch across j iterations, the bitmap tile is
-written once per (i, j) step. Objectives are tiny (M <= 8), so blocks are
-(block_i, M) rows vs (block_j, M) columns:
+Layout. Grid = (row blocks, column blocks), columns innermost/sequential.
+Rows arrive as (M, Ni, 1) so objective m of a row block is a (bi, 1) column
+that broadcasts along lanes; columns arrive transposed as (M, Nj) so
+objective m of 128 columns is a (1, 128) row that broadcasts along
+sublanes. Every compare therefore runs on whole (bi, 128) vreg tiles, with
+no (bi, bj, M) intermediate and no M-wide lane axis.
 
-    VMEM ≈ 2*block*M*4 B  (row/col tiles)
-         +   block*4 B    (counter scratch)
-         + block^2 * 1 B  (the dom tile)           ≈ 80 KB at block=256, M=4
-         + block*block/32*4 B (packed words tile)
+A column block is ``COLS`` = 4096 columns = 128 output words, so the bitmap
+block (bi, 128) is lane-aligned. The kernel packs word w of a block from
+lanes {w, 128 + w, ..., 31*128 + w}: bit k comes from the 128-lane slice k,
+an aligned slice. The wrapper permutes the columns of each block so that
+lane k*128 + w holds column 32*w + k, which makes this the standard
+``ref.pack_words_u32`` convention (bit j % 32 of word j // 32) with no
+in-kernel reshape of the lane axis.
 
-Indivisible N is handled by padding rows up to a block multiple with +BIG
-sentinel rows: all-BIG rows never strictly dominate anything (<= holds but <
-fails on every objective), so padding adds exactly zero to every count and
-never sets a bitmap bit; callers slice the padding off. This replaces the old
-divisor search, whose worst case (prime N) degraded to block=1 — a grid of
-N^2 single-row steps, pathological on TPU and in interpret mode.
+    VMEM per step ~ 2 * (M * bi * 512 B  +  M * 4096 * 4 B)   input blocks
+                  + 2 * bi * 512 B                            bitmap block
+                  + bi * 512 B                                counter scratch
+                  ~ 1.3 MiB at bi = 256, M = 3
+
+Padding. Rows pad up to a block multiple and columns up to a ``COLS``
+multiple with +BIG sentinels (group -1): all-BIG rows never strictly
+dominate anything (<= holds but < fails on every objective), so padding adds
+exactly zero to every count and never sets a bitmap bit; the wrapper slices
+it off.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import ref
-
-# jax <= 0.4.x names it TPUCompilerParams; >= 0.5 CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
-
 BIG = 3.0e38
+LANES = 128
+COLS = 32 * LANES          # columns per column block = 128 u32 words
 
 
 def _ceil_to(n: int, mult: int) -> int:
@@ -73,82 +72,105 @@ def _pad_rows(x, n_padded, value):
         [x, jnp.full((n_padded - n,) + x.shape[1:], value, x.dtype)])
 
 
-# ---------------------------------------------------------------------------
-# counts-only kernel (kept: the per-front peeling baseline + ga-step sizes)
-# ---------------------------------------------------------------------------
-def _count_kernel(fi_ref, fj_ref, o_ref, cnt_scr):
+def _tile(fi_ref, gi_ref, fj_ref, gj_ref, k):
+    """(bi, 128) bool: column lane-slice ``k`` of the block dominates row i
+    (within the same group)."""
+    cols = pl.ds(pl.multiple_of(k * LANES, LANES), LANES)
+    le = lt = None
+    for m in range(fi_ref.shape[0]):
+        a = fj_ref[pl.ds(m, 1), cols]            # (1, 128) dominator obj m
+        b = fi_ref[m]                            # (bi, 1) candidate obj m
+        le = (a <= b) if le is None else le & (a <= b)
+        lt = (a < b) if lt is None else lt | (a < b)
+    return le & lt & (gj_ref[:, cols] == gi_ref[...])
+
+
+def _kernel(fi_ref, gi_ref, fj_ref, gj_ref, *refs, bitmap: bool):
+    if bitmap:
+        cnt_ref, bm_ref, cnt_scr = refs
+    else:
+        cnt_ref, cnt_scr = refs
     ji = pl.program_id(1)
 
     @pl.when(ji == 0)
     def _init():
         cnt_scr[...] = jnp.zeros_like(cnt_scr)
 
-    fi = fi_ref[...]                                  # (bi, M) candidates
-    fj = fj_ref[...]                                  # (bj, M) potential dominators
-    # inactive rows are encoded as +BIG in every objective -> they never
-    # dominate anyone and everyone "dominates" them (harmless: their own
-    # count is ignored by the caller's active mask).
-    le = (fj[None, :, :] <= fi[:, None, :]).all(-1)   # (bi, bj)
-    lt = (fj[None, :, :] < fi[:, None, :]).any(-1)
-    dom = jnp.logical_and(le, lt)
-    cnt_scr[...] += dom.astype(jnp.int32).sum(axis=1)[:, None]
+    def slice_k(k, carry):
+        cnt, words = carry
+        dom = _tile(fi_ref, gi_ref, fj_ref, gj_ref, k)
+        bit = jnp.left_shift(jnp.uint32(1), k.astype(jnp.uint32))
+        return (cnt + dom.astype(jnp.int32),
+                words | jnp.where(dom, bit, jnp.uint32(0)))
+
+    cnt, words = jax.lax.fori_loop(
+        0, fj_ref.shape[1] // LANES, slice_k,
+        (jnp.zeros(cnt_scr.shape, jnp.int32),
+         jnp.zeros(cnt_scr.shape, jnp.uint32)))
+    cnt_scr[...] += cnt
+    if bitmap:
+        bm_ref[...] = words
 
     @pl.when(ji == pl.num_programs(1) - 1)
     def _finish():
-        o_ref[...] = cnt_scr[...]
+        cnt_ref[...] = cnt_scr[...].sum(axis=1, keepdims=True)
 
 
-def dominated_counts(objectives, *, block=512, interpret=False):
-    """objectives: (N, M) f32 (inactive rows pre-masked to +BIG).
-    Returns (N,) i32 dominated counts."""
-    n, m = objectives.shape
-    bs = effective_block(n, block, 8)
-    np_ = _ceil_to(n, bs)
-    padded = _pad_rows(objectives, np_, BIG)
-    nb = np_ // bs
+def _sweep(rows, cols, groups, groups_cols, *, block, bitmap, interpret):
+    ni, m = rows.shape
+    nj = cols.shape[0]
+    if groups is None:
+        groups = jnp.zeros((ni,), jnp.int32)
+    if groups_cols is None:
+        groups_cols = jnp.zeros((nj,), jnp.int32)
+    bi = effective_block(ni, block, 8)
+    ni_p, nj_p = _ceil_to(ni, bi), _ceil_to(nj, COLS)
+    fi = _pad_rows(rows.astype(jnp.float32), ni_p, BIG).T[:, :, None]
+    gi = _pad_rows(groups.astype(jnp.int32), ni_p, -1)[:, None]
+    fj = _pad_rows(cols.astype(jnp.float32), nj_p, BIG)
+    gj = _pad_rows(groups_cols.astype(jnp.int32), nj_p, -1)
+    if bitmap:
+        # lane k*128 + w of each column block holds column 32*w + k
+        def perm(x):
+            nb = nj_p // COLS
+            x = x.reshape((nb, LANES, 32) + x.shape[1:])
+            return jnp.swapaxes(x, 1, 2).reshape((nj_p,) + x.shape[3:])
+        fj, gj = perm(fj), perm(gj)
+    fj, gj = fj.T, gj[None, :]
+    out_specs = [pl.BlockSpec((bi, 1), lambda i, j: (i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((ni_p, 1), jnp.int32)]
+    if bitmap:
+        out_specs.append(pl.BlockSpec((bi, LANES), lambda i, j: (i, j)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((ni_p, nj_p // 32), jnp.uint32))
     out = pl.pallas_call(
-        _count_kernel,
-        grid=(nb, nb),
+        lambda *refs: _kernel(*refs, bitmap=bitmap),
+        grid=(ni_p // bi, nj_p // COLS),
         in_specs=[
-            pl.BlockSpec((bs, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((bs, m), lambda i, j: (j, 0)),
+            pl.BlockSpec((m, bi, 1), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((bi, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((m, COLS), lambda i, j: (0, j)),
+            pl.BlockSpec((1, COLS), lambda i, j: (0, j)),
         ],
-        out_specs=pl.BlockSpec((bs, 1), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((np_, 1), jnp.int32),
-        scratch_shapes=[pltpu.VMEM((bs, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((bi, LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(padded, padded)
-    return out[:n, 0]
+        name="dominance_pass" if bitmap else "dominated_counts",
+    )(fi, gi, fj, gj)
+    cnt = out[0][:ni, 0]
+    if not bitmap:
+        return cnt
+    return cnt, out[1][:ni, :_ceil_to(nj, 32) // 32]
 
 
-# ---------------------------------------------------------------------------
-# fused counts + packed-bitmap kernel (the single-pass selection engine)
-# ---------------------------------------------------------------------------
-def _fused_kernel(fi_ref, fj_ref, gi_ref, gj_ref, cnt_ref, bm_ref, cnt_scr):
-    ji = pl.program_id(1)
-
-    @pl.when(ji == 0)
-    def _init():
-        cnt_scr[...] = jnp.zeros_like(cnt_scr)
-
-    fi = fi_ref[...]                                  # (bi, M)
-    fj = fj_ref[...]                                  # (bj, M)
-    le = (fj[None, :, :] <= fi[:, None, :]).all(-1)   # (bi, bj)
-    lt = (fj[None, :, :] < fi[:, None, :]).any(-1)
-    # group mask: dominance only counts within a group (donor-batched
-    # islands run in one launch; padding carries group -1 = no group)
-    same = gj_ref[...][None, :, 0] == gi_ref[...][:, None, 0]
-    dom = jnp.logical_and(jnp.logical_and(le, lt), same)
-    cnt_scr[...] += dom.astype(jnp.int32).sum(axis=1)[:, None]
-
-    bi, bj = dom.shape
-    bm_ref[...] = ref.pack_words_u32(dom.reshape(bi, bj // 32, 32))
-
-    @pl.when(ji == pl.num_programs(1) - 1)
-    def _finish():
-        cnt_ref[...] = cnt_scr[...]
+def dominated_counts(objectives, *, block=256, interpret=False):
+    """objectives: (N, M) f32 (inactive rows pre-masked to +BIG).
+    Returns (N,) i32 dominated counts — the per-front peeling baseline."""
+    return _sweep(objectives, objectives, None, None, block=block,
+                  bitmap=False, interpret=interpret)
 
 
 def dominance_pass(rows, cols=None, groups=None, groups_cols=None, *,
@@ -166,40 +188,5 @@ def dominance_pass(rows, cols=None, groups=None, groups_cols=None, *,
     if cols is None:
         cols = rows
         groups_cols = groups
-    ni, m = rows.shape
-    nj = cols.shape[0]
-    if groups is None:
-        groups = jnp.zeros((ni,), jnp.int32)
-    if groups_cols is None:
-        groups_cols = jnp.zeros((nj,), jnp.int32)
-    # j blocks pack 32 columns per output word -> multiple-of-32 blocks
-    bs = effective_block(max(ni, nj), block, 32)
-    ni_p, nj_p = _ceil_to(ni, bs), _ceil_to(nj, bs)
-    rows_p = _pad_rows(rows, ni_p, BIG)
-    cols_p = _pad_rows(cols, nj_p, BIG)
-    gi = _pad_rows(groups.astype(jnp.int32)[:, None], ni_p, -1)
-    gj = _pad_rows(groups_cols.astype(jnp.int32)[:, None], nj_p, -1)
-    wpb = bs // 32
-    cnt, bm = pl.pallas_call(
-        _fused_kernel,
-        grid=(ni_p // bs, nj_p // bs),
-        in_specs=[
-            pl.BlockSpec((bs, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((bs, m), lambda i, j: (j, 0)),
-            pl.BlockSpec((bs, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bs, 1), lambda i, j: (j, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((bs, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((bs, wpb), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((ni_p, 1), jnp.int32),
-            jax.ShapeDtypeStruct((ni_p, nj_p // 32), jnp.uint32),
-        ],
-        scratch_shapes=[pltpu.VMEM((bs, 1), jnp.int32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(rows_p, cols_p, gi, gj)
-    return cnt[:ni, 0], bm[:ni, :_ceil_to(nj, 32) // 32]
+    return _sweep(rows, cols, groups, groups_cols, block=block, bitmap=True,
+                  interpret=interpret)

@@ -25,13 +25,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax <= 0.4.x names it TPUCompilerParams; >= 0.5 CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
 
 NEG_INF = -1e30
 
@@ -111,7 +104,7 @@ def flash_attention_fwd(q, k, v, *, causal=True, block_q=512, block_k=512,
         scratch_shapes=[pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, 1), jnp.float32),
                         pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -236,7 +229,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, block_q=512,
                                lambda bi, hi, qi, ki: (bi, hi, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -264,7 +257,7 @@ def flash_attention_bwd(q, k, v, out, lse, do, *, causal=True, block_q=512,
                    jax.ShapeDtypeStruct((b, h, s, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

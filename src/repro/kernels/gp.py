@@ -50,14 +50,6 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import ref
 from repro.kernels.dominance import _ceil_to, _pad_rows, effective_block
 
-# jax <= 0.4.x names it TPUCompilerParams; >= 0.5 CompilerParams
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-if _CompilerParams is None:
-    raise ImportError(
-        "jax.experimental.pallas.tpu exposes neither CompilerParams nor "
-        "TPUCompilerParams; unsupported jax version")
-
 
 def _sqdist_kernel(x1_ref, x2_ref, o_ref):
     o_ref[...] = ref.gp_sqdist_ref(x1_ref[...], x2_ref[...])
@@ -82,7 +74,7 @@ def _tiled_call(kernel, x1, x2, *, block, interpret):
         ],
         out_specs=pl.BlockSpec((bs, bs), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n1_p, n2_p), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(_pad_rows(x1.astype(jnp.float32), n1_p, 0.0),

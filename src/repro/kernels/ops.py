@@ -1,9 +1,12 @@
-"""jit'd wrappers around the Pallas kernels.
+"""jit'd wrappers around the Pallas kernels: the routes each kernel takes.
 
-On TPU the kernels compile natively; everywhere else (this CPU container)
-they run in interpret mode for small shapes, and callers that cannot afford
-interpret-mode cost (dry-run lowering, large CPU tests) use the jnp reference
-path via the ``*_available`` gates.
+On TPU (``on_tpu()``) every wrapper runs its compiled Pallas kernel; that is
+the only route there, and interpret mode and the jnp reference are never
+reached. On the CPU, where the tests run, a wrapper runs the kernel in
+Pallas interpret mode while its grid is small and the jitted jnp reference
+from kernels/ref.py beyond that (interpret cost grows with the grid);
+dry-run lowering always takes the reference. The references are the
+oracles the tests and chip_smoke.py hold the kernels to (``ref.TOL``).
 """
 from __future__ import annotations
 
@@ -143,19 +146,20 @@ def dominated_counts(objectives):
 # --------------------------------------------------------------------------
 # GP covariance assembly (surrogate-assisted exploration)
 # --------------------------------------------------------------------------
-# Same routing discipline as dominance: the three paths (TPU kernel, CPU
-# interpret for small grids, jitted jnp expanded-form reference otherwise)
-# compute through the same ref.gp_sqdist_ref / ref.gp_kernel_fn helpers and
-# are bit-identical; the gate only decides who executes them. The reference
-# route is ALWAYS jitted: XLA's jit pipeline forms FMAs that op-by-op eager
-# execution does not, and the Pallas kernel (interpret or compiled) runs on
-# the jit side of that line — so "bit-exact" here means bit-exact among
-# jit-compiled executions, which is where every engine path runs.
-# Single-tile grids only: embedded in a jitted caller, a one-step interpret
-# kernel costs the same as the inlined reference, but the interpreter's
-# grid sequencing loses to the one-shot jnp assembly from ~4 steps up (and
-# an EAGER interpret call pays ~200 ms of per-call trace/lower overhead
-# regardless — eager callers always want the jitted reference route).
+# Same routing as dominance: compiled kernel on TPU; on the CPU the kernel
+# in interpret mode for single-tile grids, the jitted reference otherwise.
+# All routes assemble distances through ref.gp_sqdist_ref / ref.gp_kernel_fn,
+# but they are not bit-identical: a compiler may contract a*b+c into an FMA
+# on one side only or reassociate a reduction (and on the chip the VPU
+# rounds as it does), so the contract is agreement within ref.TOL
+# ["gp_sqdist"] / ["gp_matrix"] — f32 rounding, which a bf16 computation
+# fails. The reference route is ALWAYS jitted: eager op-by-op execution
+# rounds differently again and is no engine path. Single-tile grids only:
+# embedded in a jitted caller, a one-step interpret kernel costs the same
+# as the inlined reference, but the interpreter's grid sequencing loses to
+# the one-shot jnp assembly from ~4 steps up (and an EAGER interpret call
+# pays ~200 ms of per-call trace/lower overhead regardless — eager callers
+# always want the jitted reference route).
 _GP_INTERPRET_STEPS = 1
 
 _gp_sqdist_ref_jit = jax.jit(ref.gp_sqdist_ref)
@@ -201,14 +205,16 @@ def gp_matrix(x1, x2, *, kind="matern52", lengthscale=0.2, variance=1.0):
 # --------------------------------------------------------------------------
 # Routing discipline as above: TPU kernel, CPU interpret for small grids,
 # jitted blocked oracle otherwise — all through the shared tile helpers in
-# kernels/ref.py with the same (block, block) dot shapes, so the three paths
-# are bitwise identical per (shape, block). The factor IS block-size-
+# kernels/ref.py with the same (block, block) dot shapes, so on the CPU the
+# kernel and the oracle are bitwise identical per (shape, block); on the
+# chip the MXU accumulates its tile dots in its own order and the two agree
+# within ref.TOL["chol"] / ["tri_solve"]. The factor IS block-size-
 # dependent at the last bit (see the contract comment in ref.py), so these
 # wrappers take block= explicitly and default it to one pinned value.
 # The oracle route is the ENGINE route on CPU (gemm-bound left-looking
 # schedule, ~2-4x over the vmapped LAPACK grid at n=4096 — see
 # benchmarks gp_chol_4096); interpret mode exists to execute the actual
-# kernel program on small shapes so tests pin kernel == oracle bitwise.
+# kernel program on small shapes so tests pin kernel == oracle.
 # The blocked grid must NOT be vmapped on CPU (measured pathological);
 # sweep lengthscale grids with a python loop under one jit instead.
 _CHOL_INTERPRET_STEPS = 64
@@ -338,7 +344,8 @@ def tri_solve(l, b, *, trans=False, block=_CHOL_BLOCK,
 def dominance_pass(rows, cols=None, groups=None, groups_cols=None):
     """Fused single-pass sweep -> (counts (Ni,) i32, bitmap (Ni, W) u32).
     Kernel on TPU, interpret mode for small CPU grids, jnp reference
-    otherwise — all three are bit-exact (integer outputs)."""
+    otherwise — all three are bit-exact (integer outputs). The CPU gate
+    counts work in 256x256 pair tiles."""
     _PAIRWISE_PASSES[0] += 1
     ni = rows.shape[0]
     nj = cols.shape[0] if cols is not None else ni

@@ -7,6 +7,33 @@ import jax
 import jax.numpy as jnp
 
 
+# Kernel-vs-oracle tolerances for f32 outputs, shared by the tests and
+# chip_smoke.py (``np.testing.assert_allclose(got, want, **TOL[name])``).
+# A kernel and its jitted oracle evaluate the same expression, but a
+# compiler may contract a*b+c into one FMA on one side only or reassociate
+# a reduction, and on the TPU the MXU and the VPU accumulate in their own
+# orders: the two agree to f32 rounding, not bit for bit. Each bound is a
+# small multiple of f32 rounding at the magnitude compared, and at least
+# 8x below bf16's ulp at that magnitude (2^-7 = 7.8e-3 relative), so a
+# kernel computing in bf16 fails it. Integer outputs (dominance counts
+# and bitmaps) are compared exactly.
+TOL = {
+    # chemical fields of magnitude ~1; same terms added in the same order
+    "diffusion": dict(rtol=1e-6, atol=1e-6),
+    # squared distances up to 4 d; n1 + n2 - 2 x.y cancels, so absolute
+    "gp_sqdist": dict(rtol=1e-5, atol=1e-5),
+    # covariances in [0, variance]
+    "gp_matrix": dict(rtol=1e-5, atol=4e-6),
+    # a fitted GP: factor, weights, posterior mean and covariance
+    "gp_posterior": dict(rtol=1e-4, atol=1e-6),
+    # factor of K + 1e-2 I for 1000 unit-square points (condition ~2e4,
+    # entries <= 1): forward error ~ condition x f32 rounding
+    "chol": dict(rtol=0.0, atol=5e-4),
+    # L X = B against that factor, |X| <= ~50
+    "tri_solve": dict(rtol=1e-5, atol=1e-4),
+}
+
+
 def flash_attention_ref(q, k, v, *, causal=True):
     """q: (B,H,S,D); k,v: (B,KH,S,D). Plain softmax attention with GQA."""
     b, h, s, d = q.shape
@@ -158,6 +185,15 @@ def gp_matrix_naive_ref(x1, x2, *, kind="matern52", lengthscale=0.2,
 # block-size-DEPENDENT at the last bit (different tile dots round
 # differently); callers pin block= where bitwise stability matters.
 
+def tile_dot(a, b):
+    """THE tile matmul of the blocked factor and solve, kernel and oracle
+    alike. Full f32 precision: on the TPU a default-precision f32 dot
+    rounds its operands to bf16 (XLA and Mosaic differently), which would
+    leave the Cholesky factor of a GP covariance with ~3 correct digits.
+    On the CPU the precision flag changes nothing."""
+    return jnp.dot(a, b, precision=jax.lax.Precision.HIGHEST)
+
+
 CHOL_BASE = 64   # fori-loop base-case tile edge (all blocks are multiples)
 
 
@@ -218,8 +254,8 @@ def chol_tile_ref(a):
     h = b // 2
     a11, a21, a22 = a[:h, :h], a[h:, :h], a[h:, h:]
     l11 = chol_tile_ref(a11)
-    l21 = jnp.dot(a21, tri_inv_tile_ref(l11).T)
-    l22 = chol_tile_ref(a22 - jnp.dot(l21, l21.T))
+    l21 = tile_dot(a21, tri_inv_tile_ref(l11).T)
+    l22 = chol_tile_ref(a22 - tile_dot(l21, l21.T))
     z = jnp.zeros((h, b - h), a.dtype)
     return jnp.block([[l11, z], [l21, l22]])
 
@@ -236,7 +272,7 @@ def tri_inv_tile_ref(l):
     i22 = tri_inv_tile_ref(l[h:, h:])
     z = jnp.zeros((h, b - h), l.dtype)
     return jnp.block([[i11, z],
-                      [-jnp.dot(i22, jnp.dot(l[h:, :h], i11)), i22]])
+                      [-tile_dot(i22, tile_dot(l[h:, :h], i11)), i22]])
 
 
 def gp_tile_ref(x1, x2, row0, col0, n, *, kind, lengthscale, nugget):
@@ -280,14 +316,14 @@ def _chol_left_tiles(tiles, nb, block):
         for i in range(k, nb):
             s = tiles[(i, k)]
             for j in range(k):
-                s = s - jnp.dot(out[(i, j)], out[(k, j)].T)
+                s = s - tile_dot(out[(i, j)], out[(k, j)].T)
             col[i] = s
         lkk = chol_tile_ref(col[k])
         out[(k, k)] = lkk
         if k < nb - 1:
             linv_t = tri_inv_tile_ref(lkk).T
             for i in range(k + 1, nb):
-                out[(i, k)] = jnp.dot(col[i], linv_t)
+                out[(i, k)] = tile_dot(col[i], linv_t)
     z = jnp.zeros((block, block), jnp.float32)
     return jnp.concatenate(
         [jnp.concatenate([out[(i, j)] if j <= i else z for j in range(nb)],
@@ -345,9 +381,9 @@ def tri_solve_blocked_ref(l, b, *, trans=False, block=256, rhs_block=256):
             js = range(i) if not trans else range(i + 1, nb)
             for j in js:
                 lij = ltile(i, j) if not trans else ltile(j, i).T
-                s = s - jnp.dot(lij, xs[j])
+                s = s - tile_dot(lij, xs[j])
             di = linv[i] if not trans else linv[i].T
-            xs[i] = jnp.dot(di, s)
+            xs[i] = tile_dot(di, s)
         cols.append(jnp.concatenate(xs, axis=0))
     return jnp.concatenate(cols, axis=1)
 

@@ -31,6 +31,7 @@ from repro.evolution import (NSGA2Config, ga, init_island_state, make_epoch,
                              pareto_front, run_islands)
 from repro.explore import (MOSurrogateConfig, SurrogateConfig,
                            replicated_batch, run_surrogate, run_surrogate_mo)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import init_distributed, make_host_mesh, \
     make_island_mesh
 from repro.runtime import sharding as shd
@@ -193,10 +194,11 @@ def calibrate(*, reduced: bool = True, n_islands: int = 8, mu: int = 16,
             f"({evals / max(dt, 1e-9) * 3600:.0f} evals/hour on "
             f"{len(jax.devices())} host device(s))")
 
-    mask = np.asarray(pareto_front(state.archive))
+    archive = jax.device_get(state.archive)   # one-device readout
+    mask = np.asarray(pareto_front(archive))
     front = {
-        "genomes": np.asarray(state.archive.genomes)[mask].tolist(),
-        "objectives": np.asarray(state.archive.objectives)[mask].tolist(),
+        "genomes": archive.genomes[mask].tolist(),
+        "objectives": archive.objectives[mask].tolist(),
         "evaluations": evals,
         "wall_s": dt,
     }
@@ -503,6 +505,7 @@ def main():
     ap.add_argument("--acquisition", choices=("qei", "qucb"), default="qei")
     ap.add_argument("--out", default="/tmp/ants")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.distributed or args.num_processes or args.coordinator:
         init_distributed(coordinator=args.coordinator,
                          num_processes=args.num_processes,

@@ -10,14 +10,10 @@ import jax
 
 
 def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across API revisions: ``axis_types`` (and the
-    ``AxisType`` enum) only exist on newer jax; older versions default to
-    auto sharding semantics anyway, so omit the argument there."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis in ``Auto`` sharding mode (the
+    sharding rules in runtime/sharding.py constrain, they do not type)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -49,18 +49,10 @@ _FSDP_MIN_SIZE = 1 << 20    # params smaller than 1M elements stay replicated
 
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
-    """Build a ``jax.sharding.AbstractMesh`` across jax API revisions.
-
-    jax <= 0.4.35 took ``AbstractMesh(shape, names)``; 0.4.37 takes a single
-    ``((name, size), ...)`` tuple; >= 0.5 takes ``(shape, names)`` again with
-    keyword-only axis types. Centralising the construction here keeps tests
-    and resolver callers insulated from the churn.
-    """
+    """A device-free ``jax.sharding.AbstractMesh`` of the given axis sizes
+    (the resolver's tests and dry runs need no devices)."""
     from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(names, sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(sizes), tuple(names))
+    return AbstractMesh(tuple(sizes), tuple(names))
 
 
 def _axes_fit(mesh: Mesh, cand: Tuple[str, ...], dim: int,
@@ -211,7 +203,6 @@ def sharded_dominance_pass(objectives, groups=None):
     if n_shards <= 1 or objectives.ndim != 2:
         return kops.dominance_pass(objectives, groups=groups)
 
-    from jax.experimental.shard_map import shard_map
     g = (groups if groups is not None
          else jnp.zeros((n,), jnp.int32)).astype(jnp.int32)
     n_p = _ceil_to(n, n_shards * 32)
@@ -233,11 +224,11 @@ def sharded_dominance_pass(objectives, groups=None):
                                             (shard * rows.shape[0],))
         return jax.lax.psum(full, axes), bm
 
-    fn = shard_map(
+    fn = jax.shard_map(
         sweep, mesh=mesh,
         in_specs=(P(axes, None), P(None, None), P(axes, None), P(None, None)),
         out_specs=(P(None), P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )
     g2 = g[:, None]
     cnt, bm = fn(objectives, objectives, g2, g2)

@@ -240,3 +240,79 @@ def test_chaos_device_pool_stays_bit_exact_at_35pct_faults():
         print("CHAOS_OK", res.attempts)
     """, 4)
     assert "CHAOS_OK" in out
+
+
+# ---------------------------------------------------------------------------
+# device errors surface at once; only ragged fan-outs leave the device
+# ---------------------------------------------------------------------------
+def _device_failure(ctx):
+    """A task whose compiled program fails at run time on the device: the
+    runtime raises ``jax.errors.JaxRuntimeError``."""
+    import jax.numpy as jnp
+
+    def boom(v):
+        raise RuntimeError("device fault")
+
+    out = jax.jit(lambda v: jax.pure_callback(boom, v, v))(
+        jnp.float32(ctx["x"]))
+    return {"y": float(out)}
+
+
+def test_device_error_is_not_retried_by_environment_or_pool():
+    from repro.core.faults import is_device_error
+    task = PyTask("devfail", _device_failure, inputs=(x,), outputs=(y,))
+    env = DeviceEnvironment(jax.local_devices()[:1], retries=3,
+                            backoff_s=0.0)
+    with pytest.raises(jax.errors.JaxRuntimeError) as err:
+        env.submit(task, Context(x=1.0))
+    assert is_device_error(err.value)
+    assert env.stats.retried == 0 and env.stats.failed == 1
+    pool = EnvironmentPool(make_device_members(None, 1), retries=4,
+                           backoff_s=0.0)
+    try:
+        with pytest.raises(jax.errors.JaxRuntimeError):
+            pool.submit(task, Context(x=1.0))
+        snap = pool.stats.snapshot()
+        assert snap["resubmissions"] == 0 and snap["failed"] == 1
+        assert snap["in_flight"] == 0
+    finally:
+        pool.shutdown()
+
+
+def test_transient_errors_still_retry():
+    """An ordinary task exception keeps its transient semantics."""
+    from repro.core.faults import is_device_error
+    calls = []
+
+    def flaky(ctx):
+        calls.append(1)
+        if len(calls) < 3:
+            raise OSError("transient")
+        return {"y": ctx["x"]}
+
+    assert not is_device_error(OSError("transient"))
+    env = DeviceEnvironment(jax.local_devices()[:1], retries=3,
+                            backoff_s=0.0)
+    out = env.submit(PyTask("flaky", flaky, inputs=(x,), outputs=(y,)),
+                     Context(x=2.0))
+    assert out["y"] == 2.0 and env.stats.retried == 2
+
+
+def test_device_map_explore_host_path_only_for_ragged_contexts():
+    import numpy as np
+    sq = JaxTask("sq", lambda x: {"y": x * x}, inputs=(x,), outputs=(y,))
+    env = DeviceEnvironment(jax.local_devices()[:1])
+    # ragged shapes cannot be lanes: the host path runs them one by one
+    ragged = [Context(x=np.ones(2, np.float32)),
+              Context(x=np.ones(3, np.float32))]
+    outs = env.map_explore(sq, ragged)
+    assert [np.asarray(o["y"]).shape for o in outs] == [(2,), (3,)]
+    assert env.last_lane_devices is None
+    # a batchable fan-out whose program fails raises, no host fallback
+    bad = JaxTask("bad", lambda x: {"y": jax.pure_callback(
+        lambda v: (_ for _ in ()).throw(RuntimeError("device fault")),
+        jax.ShapeDtypeStruct((), np.float32), x,
+        vmap_method="sequential")},
+        inputs=(x,), outputs=(y,))
+    with pytest.raises(jax.errors.JaxRuntimeError):
+        env.map_explore(bad, [Context(x=np.float32(i)) for i in range(4)])

@@ -72,6 +72,22 @@ def test_diffusion_sweep(n, w):
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("n,w", [(130, 72), (5, 17)])
+def test_diffusion_within_shared_tolerance(n, w):
+    """The chip's tolerance (ref.TOL, used by chip_smoke.py) holds in
+    interpret mode too: 130 lanes pad to two 128-lane blocks at the
+    paper's world width; W = 17 leaves a partial row strip."""
+    ks = jax.random.split(jax.random.key(n + w), 3)
+    chem = jax.random.uniform(ks[0], (n, w, w), jnp.float32)
+    rate = jax.random.uniform(ks[1], (n,), jnp.float32)
+    evap = jax.random.uniform(ks[2], (n,), jnp.float32)
+    out = diffuse_pallas(chem, rate, evap, interpret=True)
+    assert out.shape == (n, w, w)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(ref.diffuse_evaporate_ref(chem, rate, evap)),
+        **ref.TOL["diffusion"])
+
+
 def test_diffusion_conserves_mass_without_evaporation():
     key = jax.random.key(5)
     chem = jax.random.uniform(key, (4, 24, 24), jnp.float32)
@@ -160,3 +176,27 @@ def test_flash_fwd_lse_matches_softmax():
     np.testing.assert_allclose(
         np.asarray(out), np.asarray(ref.flash_attention_ref(q, k, v)),
         atol=1e-5, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the shared kernel-vs-oracle tolerances still catch reduced precision
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("name", ["diffusion", "gp_sqdist", "gp_matrix"])
+def test_tolerance_rejects_a_bf16_computation(name):
+    """``ref.TOL`` admits f32 rounding differences only: the same oracle
+    evaluated in bfloat16 must fall outside it."""
+    k = jax.random.split(jax.random.key(7), 3)
+    if name == "diffusion":
+        fn = ref.diffuse_evaporate_ref
+        args = (jax.random.uniform(k[0], (4, 24, 24), jnp.float32),
+                jax.random.uniform(k[1], (4,), jnp.float32),
+                jax.random.uniform(k[2], (4,), jnp.float32))
+    else:
+        fn = ref.gp_sqdist_ref if name == "gp_sqdist" else ref.gp_matrix_ref
+        x = jax.random.uniform(k[0], (64, 4), jnp.float32) * 2.0
+        args = (x, x[::-1])
+    want = np.asarray(fn(*args))
+    low = np.asarray(fn(*[a.astype(jnp.bfloat16) for a in args]),
+                     np.float32)
+    with pytest.raises(AssertionError):
+        np.testing.assert_allclose(low, want, **ref.TOL[name])

@@ -1,0 +1,133 @@
+"""Compile-only checks of the main-path Pallas kernels for one TPU v5e chip.
+
+The TPU compiler ships with jaxlib and compiles for a chip that is described,
+not attached: these tests lower each kernel at the widths the system runs and
+let the chip's compiler accept or refuse it. They catch what interpret mode
+cannot (block shapes that break the (8, 128) tiling, lane slices the compiler
+cannot lower, kernels over the VMEM budget). Nothing runs, so they say
+nothing about results or speed; ``chip_smoke.py`` runs the same kernels on a
+chip against their references.
+
+The topology is described inside a module fixture, never at import: only one
+process may load the TPU library at a time, and every test worker imports
+this file. All compile tests live in this one file so that one worker holds
+the library.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
+
+from repro.kernels.cholesky import gp_chol_blocked, tri_solve_blocked
+from repro.kernels.diffusion import diffuse_evaporate
+from repro.kernels.dominance import dominance_pass, dominated_counts
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.gp import gp_matrix
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                      # no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip's compile is written to a persistent cache but can
+    # never be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    return Mesh(np.asarray(topo.devices), ("data",))
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower ``fn`` at ``(shape, dtype)`` pairs for the described chip and
+    assert the compiled program holds a Pallas kernel."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+F32, I32, BF16 = jnp.float32, jnp.int32, jnp.bfloat16
+
+
+def test_diffuse_evaporate_compiles_at_ants_world(one_chip):
+    # 4096 lanes of the paper's 72x72 world (configs/ants_netlogo.CONFIG)
+    _compile(diffuse_evaporate, one_chip,
+             ((4096, 72, 72), F32), ((4096,), F32), ((4096,), F32))
+
+
+@pytest.mark.parametrize("n", [256, 8192])
+def test_dominance_pass_compiles_with_groups(one_chip, n):
+    # 256: one island archive in a single block; 8192: column blocks
+    _compile(lambda f, g: dominance_pass(f, groups=g), one_chip,
+             ((n, 3), F32), ((n,), I32))
+
+
+def test_dominated_counts_compiles(one_chip):
+    _compile(dominated_counts, one_chip, ((8192, 3), F32))
+
+
+def test_gp_matrix_compiles(one_chip):
+    _compile(gp_matrix, one_chip, ((2048, 2), F32), ((2048, 2), F32))
+
+
+def test_gp_chol_compiles_at_block_256(one_chip):
+    _compile(lambda x: gp_chol_blocked(x, 1000, block=256), one_chip,
+             ((1024, 2), F32))
+
+
+def test_tri_solve_compiles_at_block_256(one_chip):
+    _compile(lambda l, b: tri_solve_blocked(l, b, block=256), one_chip,
+             ((1024, 1024), F32), ((1024, 256), F32))
+
+
+def test_flash_attention_forward_compiles_at_smollm_widths(one_chip):
+    # smollm-135m: 9 query heads over 3 kv heads, head_dim 64, 2k context
+    _compile(flash_attention, one_chip, ((1, 9, 2048, 64), BF16),
+             ((1, 3, 2048, 64), BF16), ((1, 3, 2048, 64), BF16))
+
+
+def test_island_merge_compiles_on_a_four_chip_mesh(four_chips, monkeypatch):
+    """The island stages on a ("data",) mesh of four chips: the TPU
+    compiler cannot partition a Pallas kernel itself, so every kernel of
+    the program must sit inside a shard_map (island-local top-k ranking,
+    the row-sharded archive sweep)."""
+    from repro.evolution import NSGA2Config, init_island_state
+    from repro.evolution.island import make_merge
+    from repro.kernels import ops
+    from repro.runtime import sharding as shd
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the chip's route
+    cfg = NSGA2Config(mu=16, genome_dim=2, bounds=((0., 99.),) * 2,
+                      n_objectives=3)
+    st = jax.eval_shape(lambda k: init_island_state(
+        cfg, k, n_islands=8, archive_size=256), jax.random.key(0))
+    rep = NamedSharding(four_chips, PartitionSpec())
+    isl = NamedSharding(four_chips, PartitionSpec("data"))
+
+    def place(tree, sharding):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=sharding if x.ndim else rep), tree)
+
+    with shd.use_mesh(four_chips):
+        text = jax.jit(make_merge(cfg, merge_top_k=8)).lower(
+            place(st.archive, rep), place(st.islands, isl)).compile(
+            ).as_text()
+    assert "tpu_custom_call" in text
