@@ -100,33 +100,40 @@ def _lane_step(cfg: AntsConfig, chem, food, ant_pos, carrying, key, nest,
     chemical-drop / food-decrement scatter results."""
     w = cfg.world_size
     p = cfg.population
-    # neighbour gather
-    npos = ant_pos[:, None, :] + _OFFSETS[None, :, :]      # (P,8,2)
-    inb = ((npos >= 0) & (npos < w)).all(-1)               # (P,8)
-    npc = jnp.clip(npos, 0, w - 1)
-    chem_n = jnp.where(inb, chem[npc[..., 0], npc[..., 1]], 0.0)
-    gumbel = jax.random.gumbel(key, (p, 8))
-    # forage: follow chemical above sniff threshold, else wander
-    sniff = jnp.where(chem_n > 0.05, chem_n, 0.0)
-    forage = jnp.where(inb, jnp.log1p(sniff) * 8.0 + gumbel, -1e9)
-    # return: move toward nest (precomputed per-patch descent scores)
-    ret = jnp.where(inb, -toward_nest_cached[npc[..., 0], npc[..., 1]]
-                    + 0.5 * gumbel, -1e9)
-    scores = jnp.where(carrying[:, None], ret, forage)
-    choice = jnp.argmax(scores, axis=-1)
-    new_pos = npc[jnp.arange(p), choice]
+    # phases of the tick as named scopes: each scope names the device
+    # operations it compiles to (op_name metadata) in a profiler trace
+    with jax.named_scope("ants.sense"):
+        # neighbour gather
+        npos = ant_pos[:, None, :] + _OFFSETS[None, :, :]      # (P,8,2)
+        inb = ((npos >= 0) & (npos < w)).all(-1)               # (P,8)
+        npc = jnp.clip(npos, 0, w - 1)
+        chem_n = jnp.where(inb, chem[npc[..., 0], npc[..., 1]], 0.0)
+    with jax.named_scope("ants.rng"):
+        gumbel = jax.random.gumbel(key, (p, 8))
+    with jax.named_scope("ants.sense"):
+        # forage: follow chemical above sniff threshold, else wander
+        sniff = jnp.where(chem_n > 0.05, chem_n, 0.0)
+        forage = jnp.where(inb, jnp.log1p(sniff) * 8.0 + gumbel, -1e9)
+        # return: move toward nest (precomputed per-patch descent scores)
+        ret = jnp.where(inb, -toward_nest_cached[npc[..., 0], npc[..., 1]]
+                        + 0.5 * gumbel, -1e9)
+        scores = jnp.where(carrying[:, None], ret, forage)
+        choice = jnp.argmax(scores, axis=-1)
 
-    on_food = food[new_pos[:, 0], new_pos[:, 1]] > 0
-    on_nest = nest[new_pos[:, 0], new_pos[:, 1]]
-    pickup = (~carrying) & on_food
-    dropoff = carrying & on_nest
-    new_carrying = (carrying | pickup) & ~dropoff
+    with jax.named_scope("ants.move"):
+        new_pos = npc[jnp.arange(p), choice]
+        on_food = food[new_pos[:, 0], new_pos[:, 1]] > 0
+        on_nest = nest[new_pos[:, 0], new_pos[:, 1]]
+        pickup = (~carrying) & on_food
+        dropoff = carrying & on_nest
+        new_carrying = (carrying | pickup) & ~dropoff
 
-    food = food.at[new_pos[:, 0], new_pos[:, 1]].add(
-        -pickup.astype(jnp.float32))
-    food = jnp.maximum(food, 0.0)
-    chem_drop = jnp.zeros_like(chem).at[new_pos[:, 0], new_pos[:, 1]].add(
-        60.0 * new_carrying.astype(jnp.float32))
+    with jax.named_scope("ants.deposit"):
+        food = food.at[new_pos[:, 0], new_pos[:, 1]].add(
+            -pickup.astype(jnp.float32))
+        food = jnp.maximum(food, 0.0)
+        chem_drop = jnp.zeros_like(chem).at[new_pos[:, 0], new_pos[:, 1]].add(
+            60.0 * new_carrying.astype(jnp.float32))
     return new_pos, new_carrying, food, chem_drop
 
 
@@ -142,17 +149,23 @@ def make_step(cfg: AntsConfig):
 
     def step(state: AntsState, tick, diffusion, evaporation) -> AntsState:
         """diffusion/evaporation: (N,) fractions in [0,1]."""
-        keys = jax.vmap(jax.random.split)(state.rng)       # (N,2,key)
-        rng, move_keys = keys[:, 0], keys[:, 1]
+        with jax.named_scope("ants.rng"):
+            keys = jax.vmap(jax.random.split)(state.rng)       # (N,2,key)
+            rng, move_keys = keys[:, 0], keys[:, 1]
         new_pos, carrying, food, chem_drop = lane_step(
             state.chem, state.food, state.ant_pos, state.carrying, move_keys)
-        chem = state.chem + chem_drop
-        chem = kops.diffuse_evaporate(
-            chem.astype(jnp.float32), diffusion,
-            evaporation).astype(state.chem.dtype)
-        src_left = jnp.einsum("kij,nij->nk", masks.astype(jnp.float32), food)
-        newly_empty = (src_left <= 0) & (state.ticks_empty == cfg.max_ticks)
-        ticks_empty = jnp.where(newly_empty, tick, state.ticks_empty)
+        with jax.named_scope("ants.deposit"):
+            chem = state.chem + chem_drop
+        with jax.named_scope("ants.diffuse"):
+            chem = kops.diffuse_evaporate(
+                chem.astype(jnp.float32), diffusion,
+                evaporation).astype(state.chem.dtype)
+        with jax.named_scope("ants.sources"):
+            src_left = jnp.einsum("kij,nij->nk", masks.astype(jnp.float32),
+                                  food)
+            newly_empty = (src_left <= 0) & (
+                state.ticks_empty == cfg.max_ticks)
+            ticks_empty = jnp.where(newly_empty, tick, state.ticks_empty)
         return AntsState(chem, food, new_pos, carrying, ticks_empty, rng)
 
     return step

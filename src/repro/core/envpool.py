@@ -43,6 +43,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
+
 from repro.core.environment import Environment
 from repro.core.faults import interruptible_sleep, is_device_error
 from repro.core.prototype import Context
@@ -292,43 +294,47 @@ class EnvironmentPool:
         fault injection, and fingerprint verification to
         ``Environment.attempt_once``; adds the pool-level bookkeeping
         (balancer accounting, pool stats, per-attempt provenance entry)."""
-        a_t0 = time.monotonic()
-        err: Optional[BaseException] = None
-        with self._lock:
-            m.inflight += 1
-        # Every attempt counts as submitted — not only the winners —
-        # otherwise per-member provenance breaks the invariant
-        # submitted == completed + failed + hung + corrupted
-        # (attempt_once bumps the three failure counters itself).
-        with m.env._lock:
-            m.env.stats.submitted += 1
-        try:
-            out = m.env.attempt_once(task, context, attempt=round_i)
-            with m.env._lock:
-                m.env.stats.completed += 1
-            return out
-        except TaskError as e:
-            err = e                    # recorded, but never a pool retry
-            raise
-        except BaseException as e:
-            err = e
-            counter = {"hang": "hung_attempts", "corrupt": "corrupt_attempts",
-                       "fail": "failed_attempts"}[m.env.attempt_outcome(e)]
-            self.stats.inc(**{counter: 1})
-            raise
-        finally:
-            wall = time.monotonic() - a_t0
-            outcome = m.env.attempt_outcome(err)
+        # the attempt's host span (recorded while a profiler trace runs)
+        with jax.profiler.TraceAnnotation("repro.pool.attempt",
+                                          member=m.name, round=round_i):
+            a_t0 = time.monotonic()
+            err: Optional[BaseException] = None
             with self._lock:
-                m.inflight -= 1
-                m.busy_s += wall
-                if err is None:
-                    m.completed += 1
-                meta.setdefault("attempts", []).append({
-                    "environment": m.name, "outcome": outcome,
-                    "wall_s": wall,
-                    "error": None if err is None
-                    else f"{type(err).__name__}: {err}"})
+                m.inflight += 1
+            # Every attempt counts as submitted — not only the winners —
+            # otherwise per-member provenance breaks the invariant
+            # submitted == completed + failed + hung + corrupted
+            # (attempt_once bumps the three failure counters itself).
+            with m.env._lock:
+                m.env.stats.submitted += 1
+            try:
+                out = m.env.attempt_once(task, context, attempt=round_i)
+                with m.env._lock:
+                    m.env.stats.completed += 1
+                return out
+            except TaskError as e:
+                err = e                    # recorded, but never a pool retry
+                raise
+            except BaseException as e:
+                err = e
+                counter = {"hang": "hung_attempts",
+                           "corrupt": "corrupt_attempts",
+                           "fail": "failed_attempts"}[m.env.attempt_outcome(e)]
+                self.stats.inc(**{counter: 1})
+                raise
+            finally:
+                wall = time.monotonic() - a_t0
+                outcome = m.env.attempt_outcome(err)
+                with self._lock:
+                    m.inflight -= 1
+                    m.busy_s += wall
+                    if err is None:
+                        m.completed += 1
+                    meta.setdefault("attempts", []).append({
+                        "environment": m.name, "outcome": outcome,
+                        "wall_s": wall,
+                        "error": None if err is None
+                        else f"{type(err).__name__}: {err}"})
 
     def submit_async(self, task: Task, context: Context) -> "cf.Future":
         """Future-returning variant of :meth:`submit_traced` — resolves to

@@ -154,9 +154,19 @@ def make_chunk_task(cfg: NSGA2Config, eval_fn: Callable, seed: int):
     jeval = jax.jit(eval_fn)
 
     def fn(ctx):
+        # host spans of one chunk (jax.profiler.TraceAnnotation: recorded
+        # only while a profiler trace runs, on the device trace's clock)
+        span = jax.profiler.TraceAnnotation
         i, size = int(ctx["chunk"]), int(ctx["size"])
-        keys, genomes = population_chunk(cfg, seed, i, size)
-        return {"objectives": np.asarray(jeval(keys, genomes))}
+        with span("repro.chunk.inputs"):
+            keys, genomes = population_chunk(cfg, seed, i, size)
+        with span("repro.chunk.dispatch"):
+            out = jeval(keys, genomes)
+        with span("repro.chunk.wait"):
+            jax.block_until_ready(out)
+        with span("repro.chunk.fetch"):
+            objectives = np.asarray(out)
+        return {"objectives": objectives}
 
     return PyTask("init_chunk", fn,
                   inputs=(Val("chunk", int), Val("size", int)),

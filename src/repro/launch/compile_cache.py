@@ -21,7 +21,13 @@ def enable_compile_cache() -> str:
 
     When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
     nothing here overrides it. Otherwise the cache goes to
-    ``REPO_CACHE_DIR`` inside the checkout."""
+    ``REPO_CACHE_DIR`` inside the checkout.
+
+    The cache key keeps the programs' metadata (op names, named scopes,
+    source locations): JAX's default key leaves it out, and a program whose
+    scopes changed would then load an executable carrying the old op names,
+    which a profiler trace would show."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

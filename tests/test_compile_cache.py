@@ -41,3 +41,29 @@ def test_env_dir_wins_and_receives_the_entries(tmp_path):
     assert r.returncode == 0, r.stderr
     assert r.stdout.split() == [str(tmp_path)] * 2
     assert any(name.endswith("-cache") for name in os.listdir(tmp_path))
+
+
+def test_a_program_whose_scopes_changed_is_compiled_anew(tmp_path):
+    """Named scopes are part of the cache key: the same computation under
+    another scope name gets an entry of its own (it would otherwise load an
+    executable carrying the old op names), and the same scope hits."""
+    script = ("import sys\n"
+              "from repro.launch.compile_cache import enable_compile_cache\n"
+              "enable_compile_cache()\n"
+              "import jax, jax.numpy as jnp\n"
+              "def scoped(x):\n"
+              "    with jax.named_scope(sys.argv[1]):\n"
+              "        return x * 2 + 1\n"
+              "jax.jit(scoped)(jnp.ones(3)).block_until_ready()\n")
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": "src",
+           "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+           "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"}
+    entries = []
+    for scope in ("ants.sense", "ants.move", "ants.sense"):
+        r = subprocess.run([sys.executable, "-c", script, scope], env=env,
+                           cwd=_REPO, capture_output=True, text=True,
+                           timeout=120)
+        assert r.returncode == 0, r.stderr
+        entries.append(sorted(n for n in os.listdir(tmp_path)
+                              if n.startswith("jit_scoped")))
+    assert [len(e) for e in entries] == [1, 2, 2]
