@@ -75,6 +75,8 @@ def nest_mask(cfg: AntsConfig) -> jnp.ndarray:
     return _dist2(w, c, c) <= cfg.nest_radius ** 2
 
 
+DEPOSIT = 60.0      # chemical an ant carrying food drops on its patch a tick
+
 _OFFSETS = jnp.array([(-1, -1), (-1, 0), (-1, 1), (0, -1),
                       (0, 1), (1, -1), (1, 0), (1, 1)], jnp.int32)
 
@@ -96,8 +98,8 @@ def init_state(cfg: AntsConfig, keys) -> AntsState:
 
 def _lane_step(cfg: AntsConfig, chem, food, ant_pos, carrying, key, nest,
                toward_nest_cached):
-    """Per-lane ant logic (vmapped over lanes). Returns new ant state and the
-    chemical-drop / food-decrement scatter results."""
+    """Per-lane ant logic (vmapped over lanes). Returns the new ant state
+    and the chemical and food fields after the ants' deposit."""
     w = cfg.world_size
     p = cfg.population
     # phases of the tick as named scopes: each scope names the device
@@ -129,12 +131,98 @@ def _lane_step(cfg: AntsConfig, chem, food, ant_pos, carrying, key, nest,
         new_carrying = (carrying | pickup) & ~dropoff
 
     with jax.named_scope("ants.deposit"):
-        food = food.at[new_pos[:, 0], new_pos[:, 1]].add(
-            -pickup.astype(jnp.float32))
-        food = jnp.maximum(food, 0.0)
-        chem_drop = jnp.zeros_like(chem).at[new_pos[:, 0], new_pos[:, 1]].add(
-            60.0 * new_carrying.astype(jnp.float32))
-    return new_pos, new_carrying, food, chem_drop
+        # the field at each ant's new patch, as sensed: a one-hot select
+        # over the 8 neighbours (exact: one nonzero term), not a gather
+        here = jnp.where(jnp.arange(8) == choice[:, None], chem_n,
+                         0.0).sum(axis=1)
+        chem, food = _deposit(chem, food, here, new_pos, pickup, new_carrying)
+    return new_pos, new_carrying, food, chem
+
+
+def _deposit(chem, food, here, pos, pickup, carrying):
+    """One lane's deposit without scatter-adds: each ant in ``pickup``
+    takes a unit of ``food`` (W, W) and each ant ``carrying`` food drops
+    ``DEPOSIT`` on ``chem`` (W, W), at its patch ``pos`` (P, 2), where the
+    field holds ``here`` (P,). Returns the new (chem, food).
+
+    Both are one-hot contractions over the ants on the MXU. Food taken is
+    a per-patch count: 0/1 operands and sums of at most P are exact. The
+    chemical is added per ant, to the value ``here``, as a scatter-add of
+    the drops rounds it (one rounding per ant), and the first carrying ant
+    on each patch writes the result back: its three bf16 pieces are the
+    only nonzero terms of their patch, so their f32 sum is exact."""
+    w = food.shape[-1]
+    rows = jax.nn.one_hot(pos[:, 0], w, dtype=jnp.bfloat16)       # (P, W)
+    cols = jax.nn.one_hot(pos[:, 1], w, dtype=jnp.bfloat16)
+    taken = jnp.einsum("pi,pj->ij", rows * pickup[:, None].astype(rows.dtype),
+                       cols, preferred_element_type=jnp.float32)
+    # per ant, in one pairwise sum: the carrying ants on its patch in the
+    # low bits and those before it in the high bits, so that the first
+    # carrying ant on each patch finds the high bits clear
+    p = pos.shape[0]
+    high = 1 << p.bit_length()
+    patch = pos[:, 0] * w + pos[:, 1]
+    tally = jnp.where((patch[:, None] == patch[None, :]) & carrying[None, :],
+                      jnp.where(jnp.tri(p, k=-1, dtype=bool), high + 1, 1),
+                      0).sum(axis=1)
+    first = carrying & (tally < high)
+    count = (tally & (high - 1)).astype(jnp.float32)
+    if here.dtype == jnp.float32:
+        value = _add_repeatedly(here, count, DEPOSIT, p)
+    else:       # a lower-precision field takes the drop in its own dtype
+        value = here + (DEPOSIT * count).astype(here.dtype)
+    pieces = _bf16_pieces(jnp.where(first, value, 0.0))           # (3, P)
+    settled = jnp.einsum(
+        "kpi,kpj->ij", rows[None] * pieces[:, :, None],
+        jnp.broadcast_to(cols, (3,) + cols.shape),
+        preferred_element_type=jnp.float32)
+    # a drop leaves at least DEPOSIT less an ulp on its patch
+    chem = jnp.where(settled > 0, settled.astype(chem.dtype), chem)
+    return chem, jnp.maximum(food - taken, 0.0)
+
+
+def _bf16_pieces(x):
+    """f32 ``x`` >= 0 as three bf16 arrays (3, ...): its significand's top,
+    middle and low 8 bits, which sum back to ``x`` exactly in any order."""
+    x = x.astype(jnp.float32)
+    pieces = []
+    for _ in range(3):
+        bits = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        top = jax.lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000),
+                                           jnp.float32)
+        pieces.append(top)
+        x = x - top
+    return jnp.stack(pieces).astype(jnp.bfloat16)
+
+
+def _add_repeatedly(c, k, amount: float, most: int):
+    """``c`` with ``amount`` added ``k`` times, rounding after each
+    addition, elementwise (f32 ``c``; whole f32 ``k`` in [0, most]).
+
+    One addition per ant is what a scatter-add of the drops gives, and it
+    can differ from ``c + amount * k``, one rounding, by an ulp. This takes
+    ``most.bit_length() + 1`` passes, not ``k``: from ``amount`` up to
+    2**26 (for 60.0), ``amount`` is a multiple of the spacing of f32
+    values, so an addition that stays in a value's binade is exact and only
+    the one that crosses into the next binade rounds. Each pass jumps to
+    that crossing or to the last addition; ``most`` additions from
+    ``amount`` on cross at most ``most.bit_length()`` binades. (A field
+    holds at most 60 x ants x ticks: 7.5e6 at CONFIG.)"""
+    a = jnp.float32(amount)
+    f = jnp.where(k > 0, c + a, c)
+    r = jnp.maximum(k - 1, 0.0)
+    for _ in range(most.bit_length() + 1):
+        exponent = jax.lax.bitcast_convert_type(f, jnp.uint32) & 0x7F800000
+        top = 2 * jax.lax.bitcast_convert_type(exponent, jnp.float32)
+        # s: the first addition that reaches ``top``, estimated then fixed
+        # by exact comparisons (sums below ``top`` are representable)
+        s = jnp.ceil((top - f) * (1 / a))
+        s = jnp.where(f + a * (s - 1) >= top, s - 1, s)
+        s = jnp.where(f + a * s < top, s + 1, s)
+        m = jnp.minimum(s, r)
+        f = f + a * m
+        r = r - m
+    return f
 
 
 def make_step(cfg: AntsConfig):
@@ -152,10 +240,8 @@ def make_step(cfg: AntsConfig):
         with jax.named_scope("ants.rng"):
             keys = jax.vmap(jax.random.split)(state.rng)       # (N,2,key)
             rng, move_keys = keys[:, 0], keys[:, 1]
-        new_pos, carrying, food, chem_drop = lane_step(
+        new_pos, carrying, food, chem = lane_step(
             state.chem, state.food, state.ant_pos, state.carrying, move_keys)
-        with jax.named_scope("ants.deposit"):
-            chem = state.chem + chem_drop
         with jax.named_scope("ants.diffuse"):
             chem = kops.diffuse_evaporate(
                 chem.astype(jnp.float32), diffusion,

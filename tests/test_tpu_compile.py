@@ -73,6 +73,29 @@ def test_diffuse_evaporate_compiles_at_ants_world(one_chip):
              ((4096, 72, 72), F32), ((4096,), F32), ((4096,), F32))
 
 
+@pytest.mark.parametrize("lanes", [5, 320])
+def test_ants_tick_compiles_without_scatter_at_config(one_chip, lanes,
+                                                      monkeypatch):
+    """The whole ants evaluation at CONFIG (one individual's 5 replicate
+    lanes, and 64 individuals' 320): the tick's deposit counts compile to
+    contractions under ``ants.deposit``, with no scatter left in the loop."""
+    import re
+
+    from repro.ants import simulate_batch
+    from repro.configs.ants_netlogo import CONFIG
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the chip's route
+    keys = jax.eval_shape(lambda: jax.random.split(jax.random.key(0), lanes))
+    text = _compile(
+        lambda k, d, e: simulate_batch.__wrapped__(CONFIG, k, d, e), one_chip,
+        (keys.shape, keys.dtype), ((lanes,), F32), ((lanes,), F32))
+    assert not re.search(r"\bscatter\(", text)
+    contractions = re.findall(r'op_name="([^"]*pi,pj->ij[^"]*)"', text)
+    assert contractions
+    assert all("ants.deposit" in n for n in contractions)
+
+
 @pytest.mark.parametrize("n", [256, 8192])
 def test_dominance_pass_compiles_with_groups(one_chip, n):
     # 256: one island archive in a single block; 8192: column blocks

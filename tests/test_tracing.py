@@ -43,6 +43,16 @@ def test_the_compiled_tick_names_each_of_its_phases():
     assert found == set(PHASES)
 
 
+def test_the_compiled_tick_deposits_without_scatter():
+    """The deposit's per-patch counts are contractions, not scatter-adds,
+    and they carry the deposit's scope."""
+    text = _compiled_text()
+    assert not re.search(r"\bscatter\(", text)
+    contractions = re.findall(r'op_name="([^"]*pi,pj->ij[^"]*)"', text)
+    assert contractions
+    assert all("ants.deposit" in n for n in contractions)
+
+
 def test_the_scopes_add_metadata_and_leave_the_operations(monkeypatch):
     scoped = _compiled_text()
     # the same program traced anew with every named scope made a no-op
