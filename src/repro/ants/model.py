@@ -257,10 +257,10 @@ def make_step(cfg: AntsConfig):
     return step
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def simulate_batch(cfg: AntsConfig, keys, diffusion_rates, evaporation_rates):
-    """keys: (N,) PRNG keys; rates: (N,) NetLogo percentages in [0, 99].
-    Returns (N, 3) f32 objectives (first-empty ticks, lower = better)."""
+def _simulate(cfg: AntsConfig, keys, diffusion_rates,
+              evaporation_rates) -> AntsState:
+    """Every lane's final state after ``max_ticks`` ticks: one ``lax.scan``
+    over the ticks whose carry is the ``AntsState``."""
     diffusion = jnp.clip(diffusion_rates / 100.0, 0.0, 1.0)
     evaporation = jnp.clip(evaporation_rates / 100.0, 0.0, 1.0)
     state = init_state(cfg, keys)
@@ -271,7 +271,24 @@ def simulate_batch(cfg: AntsConfig, keys, diffusion_rates, evaporation_rates):
 
     state, _ = jax.lax.scan(tick_fn, state,
                             jnp.arange(cfg.max_ticks, dtype=jnp.int32))
+    return state
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def simulate_batch(cfg: AntsConfig, keys, diffusion_rates, evaporation_rates):
+    """keys: (N,) PRNG keys; rates: (N,) NetLogo percentages in [0, 99].
+    Returns (N, 3) f32 objectives (first-empty ticks, lower = better)."""
+    state = _simulate(cfg, keys, diffusion_rates, evaporation_rates)
     return state.ticks_empty.astype(jnp.float32)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def simulate_batch_state(cfg: AntsConfig, keys, diffusion_rates,
+                         evaporation_rates):
+    """``simulate_batch`` that also returns every lane's final state:
+    ``(objectives (N, 3) f32, AntsState)``, the same computation."""
+    state = _simulate(cfg, keys, diffusion_rates, evaporation_rates)
+    return state.ticks_empty.astype(jnp.float32), state
 
 
 def simulate(cfg: AntsConfig, key, diffusion_rate, evaporation_rate):

@@ -47,7 +47,9 @@ def merge(archive: Archive, genomes, objectives, valid=None) -> Archive:
     return Archive(pool_g[order], pool_o[order], pool_v[order])
 
 
+@jax.jit
 def pareto_front(archive: Archive):
-    """Boolean mask of rank-0 members (host-side readout helper)."""
+    """Boolean mask of rank-0 members (host-side readout helper; one
+    compiled program per archive shape, not one per call)."""
     ranks = nsga2.nondominated_ranks(archive.objectives, archive.valid)
     return archive.valid & (ranks == 0)

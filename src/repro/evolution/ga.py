@@ -45,32 +45,58 @@ def init_state(cfg: NSGA2Config, key) -> GAState:
     )
 
 
+def evaluated(out):
+    """``(objectives, lanes)`` of an evaluation's output. A fitness task
+    returns the (N, M) objectives, or ``(objectives, lanes)`` where
+    ``lanes`` is what its simulated lanes ended in (e.g. ``AntsState``)."""
+    return out if isinstance(out, tuple) else (out, None)
+
+
 def evaluate_initial(cfg: NSGA2Config, state: GAState, eval_fn) -> GAState:
     rng, k_eval = jax.random.split(state.rng)
     keys = jax.random.split(k_eval, cfg.mu)
-    objectives = eval_fn(keys, state.genomes)
+    objectives, _ = evaluated(eval_fn(keys, state.genomes))
     return state._replace(objectives=objectives,
                           valid=jnp.ones((cfg.mu,), bool),
                           rng=rng,
                           evaluations=state.evaluations + cfg.mu)
 
 
-def make_step(cfg: NSGA2Config, eval_fn: Callable, lam: int) -> Callable:
-    """One (mu + lambda) NSGA-II generation as a pure function."""
+class StepRecord(NamedTuple):
+    """What one (mu + lambda) generation saw and made, beside its new
+    state: the parents before the step, the children with the keys they
+    were evaluated on (raw key data) and their objectives, and what the
+    evaluation's lanes ended in (``None`` where it returns objectives
+    only)."""
+    parent_genomes: jnp.ndarray     # (mu, D)
+    parent_objectives: jnp.ndarray  # (mu, M)
+    parent_valid: jnp.ndarray       # (mu,) bool
+    children: jnp.ndarray           # (lam, D)
+    child_keys: jnp.ndarray         # (lam, 2) u32 key data
+    child_objectives: jnp.ndarray   # (lam, M)
+    lanes: Any
 
-    def step(state: GAState) -> GAState:
+
+def make_recorded_step(cfg: NSGA2Config, eval_fn: Callable,
+                       lam: int) -> Callable:
+    """One (mu + lambda) NSGA-II generation as a pure function
+    ``state -> (state, StepRecord)``."""
+
+    def step(state: GAState):
         rng, k_off, k_eval = jax.random.split(state.rng, 3)
-        ranks = nsga2.nondominated_ranks(state.objectives, state.valid)
-        crowd = nsga2.crowding_distance(state.objectives, ranks)
-        children, _ = nsga2.make_offspring(cfg, k_off, state.genomes, ranks,
-                                           crowd, lam)
+        with jax.named_scope("islands.select"):
+            ranks = nsga2.nondominated_ranks(state.objectives, state.valid)
+            crowd = nsga2.crowding_distance(state.objectives, ranks)
+            children, _ = nsga2.make_offspring(cfg, k_off, state.genomes,
+                                               ranks, crowd, lam)
         keys = jax.random.split(k_eval, lam)
-        child_obj = eval_fn(keys, children)
+        child_obj, lanes = evaluated(eval_fn(keys, children))
         pool_g = jnp.concatenate([state.genomes, children])
         pool_o = jnp.concatenate([state.objectives, child_obj])
         pool_v = jnp.concatenate([state.valid, jnp.ones((lam,), bool)])
-        idx, _, _ = nsga2.select_mu(cfg, pool_g, pool_o, pool_v)
-        return GAState(
+        with jax.named_scope("islands.select"):
+            idx, _, _ = nsga2.select_mu(cfg, pool_g, pool_o, pool_v)
+        new = GAState(
             genomes=pool_g[idx],
             objectives=pool_o[idx],
             valid=pool_v[idx],
@@ -78,8 +104,17 @@ def make_step(cfg: NSGA2Config, eval_fn: Callable, lam: int) -> Callable:
             generation=state.generation + 1,
             evaluations=state.evaluations + lam,
         )
+        return new, StepRecord(state.genomes, state.objectives, state.valid,
+                               children, jax.random.key_data(keys),
+                               child_obj, lanes)
 
     return step
+
+
+def make_step(cfg: NSGA2Config, eval_fn: Callable, lam: int) -> Callable:
+    """One (mu + lambda) NSGA-II generation as a pure function."""
+    step = make_recorded_step(cfg, eval_fn, lam)
+    return lambda state: step(state)[0]
 
 
 def run_generational(cfg: NSGA2Config, eval_fn, key, *, lam: int,

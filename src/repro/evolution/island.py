@@ -142,32 +142,57 @@ def _per_island(fn: Callable) -> Callable:
 # ---------------------------------------------------------------------------
 # Epoch stages
 # ---------------------------------------------------------------------------
+def _repeat(fn: Callable, x, times: int):
+    """``fn`` (x -> (x, record)) applied ``times`` times in one `lax.scan`:
+    the last x and the last record."""
+    blank = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                         jax.eval_shape(fn, x)[1])
+    (x, record), _ = jax.lax.scan(lambda c, _: (fn(c[0]), None),
+                                  (x, blank), None, length=times)
+    return x, record
+
+
+class EpochRecord(NamedTuple):
+    """What an epoch's islands went through, beside its new state (none of
+    it is checkpointed): each island's record of the epoch's last GA step,
+    and the islands after evolve, before the reseed (the pool the archive
+    merge drew from). Leaves have a leading (n_islands,) dim."""
+    step: ga.StepRecord
+    evolved: ga.GAState
+
+
+def make_recorded_evolve(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
+                         steps_per_epoch: int) -> Callable:
+    """islands -> (islands after K island-local NSGA-II steps, each
+    island's ``StepRecord`` of the last step): the evaluation-heavy stage,
+    with zero cross-island communication."""
+    step = ga.make_recorded_step(cfg, eval_fn, lam)
+
+    def initial(s):
+        with jax.named_scope("islands.init_eval"):
+            return ga.evaluate_initial(cfg, s, eval_fn)
+
+    def evolve_island(istate: ga.GAState):
+        # first epoch: islands arrive unevaluated -> evaluate initial pop
+        istate = jax.lax.cond(istate.valid.any(), lambda s: s, initial,
+                              istate)
+        return _repeat(step, istate, steps_per_epoch)
+
+    def evolve(islands: ga.GAState):
+        islands = _constrain_islands(islands)
+        islands, record = _per_island(jax.vmap(evolve_island))(islands)
+        return _constrain_islands(islands), record
+
+    return evolve
+
+
 def make_evolve(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
                 steps_per_epoch: int) -> Callable:
     """islands -> islands after K island-local NSGA-II steps (the
     evaluation-heavy stage; zero cross-island communication)."""
-    step = ga.make_step(cfg, eval_fn, lam)
-
-    def evolve_island(istate: ga.GAState) -> ga.GAState:
-        # first epoch: islands arrive unevaluated -> evaluate initial pop
-        istate = jax.lax.cond(
-            istate.valid.any(),
-            lambda s: s,
-            lambda s: ga.evaluate_initial(cfg, s, eval_fn),
-            istate)
-
-        def body(s, _):
-            return step(s), None
-
-        istate, _ = jax.lax.scan(body, istate, None, length=steps_per_epoch)
-        return istate
-
-    def evolve(islands: ga.GAState) -> ga.GAState:
-        islands = _constrain_islands(islands)
-        islands = _per_island(jax.vmap(evolve_island))(islands)
-        return _constrain_islands(islands)
-
-    return evolve
+    evolve = make_recorded_evolve(cfg, eval_fn, lam=lam,
+                                  steps_per_epoch=steps_per_epoch)
+    return lambda islands: evolve(islands)[0]
 
 
 def make_merge(cfg: NSGA2Config, *, merge_top_k: int = 0) -> Callable:
@@ -256,43 +281,60 @@ def make_reseed(cfg: NSGA2Config, *, reseed_frac: float = 0.5) -> Callable:
     return reseed_islands
 
 
+def make_recorded_epoch(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
+                        steps_per_epoch: int, reseed_frac: float = 0.5,
+                        merge_top_k: int = 0) -> Callable:
+    """Returns jit-able epoch(state) -> (state, EpochRecord): the
+    bulk-synchronous composition evolve -> merge -> reseed."""
+    evolve = make_recorded_evolve(cfg, eval_fn, lam=lam,
+                                  steps_per_epoch=steps_per_epoch)
+    merge_islands = make_merge(cfg, merge_top_k=merge_top_k)
+    reseed_islands = make_reseed(cfg, reseed_frac=reseed_frac)
+
+    def epoch(state: IslandState):
+        islands, step = evolve(state.islands)
+        n_i = islands.genomes.shape[0]
+        with jax.named_scope("islands.merge"):
+            archive = merge_islands(state.archive, islands)
+        with jax.named_scope("islands.reseed"):
+            reseeded = reseed_islands(islands, archive)
+        evals = state.total_evaluations + n_i * (
+            steps_per_epoch * lam + (state.epoch == 0) * cfg.mu)
+        return (IslandState(reseeded, archive, state.epoch + 1, evals),
+                EpochRecord(step, islands))
+
+    return epoch
+
+
 def make_epoch(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
                steps_per_epoch: int, reseed_frac: float = 0.5,
                merge_top_k: int = 0) -> Callable:
     """Returns jit-able epoch(state) -> state (the bulk-synchronous
     composition evolve -> merge -> reseed)."""
-    evolve = make_evolve(cfg, eval_fn, lam=lam,
-                         steps_per_epoch=steps_per_epoch)
-    merge_islands = make_merge(cfg, merge_top_k=merge_top_k)
-    reseed_islands = make_reseed(cfg, reseed_frac=reseed_frac)
-
-    def epoch(state: IslandState) -> IslandState:
-        islands = evolve(state.islands)
-        n_i = islands.genomes.shape[0]
-        archive = merge_islands(state.archive, islands)
-        islands = reseed_islands(islands, archive)
-        evals = state.total_evaluations + n_i * (
-            steps_per_epoch * lam + (state.epoch == 0) * cfg.mu)
-        return IslandState(islands, archive, state.epoch + 1, evals)
-
-    return epoch
+    epoch = make_recorded_epoch(cfg, eval_fn, lam=lam,
+                                steps_per_epoch=steps_per_epoch,
+                                reseed_frac=reseed_frac,
+                                merge_top_k=merge_top_k)
+    return lambda state: epoch(state)[0]
 
 
 def make_superstep(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
                    steps_per_epoch: int, reseed_frac: float = 0.5,
                    merge_top_k: int = 0) -> Callable:
-    """Returns superstep(state, k) -> state: k epochs fused into ONE device
-    program via `jax.lax.scan` over the bulk-synchronous epoch. jit it with
-    k static (`static_argnums=1`) and the state donated (`donate_argnums=0`)
-    and the evolve→merge→reseed chain runs k epochs with in-place buffers
-    and zero host transfers — the device-resident hot path."""
-    epoch = make_epoch(cfg, eval_fn, lam=lam, steps_per_epoch=steps_per_epoch,
-                       reseed_frac=reseed_frac, merge_top_k=merge_top_k)
+    """Returns superstep(state, k) -> (state, EpochRecord of the k-th
+    epoch): k epochs fused into ONE device program via `jax.lax.scan` over
+    the bulk-synchronous epoch. jit it with k static (`static_argnums=1`)
+    and the state donated (`donate_argnums=0`) and the evolve→merge→reseed
+    chain runs k epochs with in-place buffers and zero host transfers — the
+    device-resident hot path. The record comes out of the same program; it
+    stays on the device until a caller reads it."""
+    epoch = make_recorded_epoch(cfg, eval_fn, lam=lam,
+                                steps_per_epoch=steps_per_epoch,
+                                reseed_frac=reseed_frac,
+                                merge_top_k=merge_top_k)
 
-    def superstep(state: IslandState, k: int) -> IslandState:
-        state, _ = jax.lax.scan(lambda s, _: (epoch(s), None), state, None,
-                                length=k)
-        return state
+    def superstep(state: IslandState, k: int):
+        return _repeat(epoch, state, k)
 
     return superstep
 
@@ -322,7 +364,8 @@ def run_islands(cfg: NSGA2Config, eval_fn, key, *, n_islands: int,
                 archive_size: int = 1024, checkpoint_fn=None,
                 merge_top_k: int = 0, reseed_frac: float = 0.5,
                 pipeline: bool = False, epochs_per_superstep: int = 0,
-                start_state: IslandState = None) -> IslandState:
+                start_state: IslandState = None,
+                epoch_hook=None) -> IslandState:
     """Host loop over supersteps (the checkpoint/restart boundary).
 
     pipeline=False: supersteps — `epochs_per_superstep` epochs scanned into
@@ -331,14 +374,27 @@ def run_islands(cfg: NSGA2Config, eval_fn, key, *, n_islands: int,
     The snapshot of superstep s is flushed to `checkpoint_fn` *after*
     superstep s+1 has been dispatched, so disk I/O overlaps device compute.
     epochs_per_superstep=0 picks the natural grain: every remaining epoch
-    in one program when there is no checkpoint_fn, else 1 (per-epoch
-    checkpoints, the historical contract).
+    in one program when there is no checkpoint_fn or epoch_hook, else 1
+    (per-epoch checkpoints, the historical contract).
     pipeline=True: the double-buffered schedule — merge of epoch k and evolve
     of epoch k+1 are dispatched back-to-back with no data dependency between
     them (the reseed feeding evolve k+1 reads the archive of epoch k-1), so
     jax's async dispatch overlaps evaluation with selection. Archive contents
     trail by one epoch relative to the synchronous schedule; the final state
-    has every epoch merged."""
+    has every epoch merged.
+
+    epoch_hook: called after `checkpoint_fn` at every boundary with the
+    snapshot and the `EpochRecord` of the superstep's last epoch, still on
+    the device (None on the pipelined schedule)."""
+
+    def _flush(snapshot, record):
+        with jax.profiler.TraceAnnotation("repro.islands.checkpoint",
+                                          epoch=int(snapshot.epoch)):
+            if checkpoint_fn is not None:
+                checkpoint_fn(snapshot)
+            if epoch_hook is not None:
+                epoch_hook(snapshot, record)
+
     state = start_state if start_state is not None else init_island_state(
         cfg, key, n_islands=n_islands, archive_size=archive_size)
     state = place_island_state(state)
@@ -357,18 +413,23 @@ def run_islands(cfg: NSGA2Config, eval_fn, key, *, n_islands: int,
         # state we created ourselves is donated.
         fn = jax.jit(sstep, static_argnums=1) if start_state is not None \
             else donating
-        grain = epochs_per_superstep or (
-            1 if checkpoint_fn is not None else epochs - e0)
+        harvest = checkpoint_fn is not None or epoch_hook is not None
+        grain = epochs_per_superstep or (1 if harvest else epochs - e0)
+        n_i = state.islands.genomes.shape[0]
+        span = jax.profiler.TraceAnnotation
         pending = None
         for s in range(e0, epochs, grain):
-            state = fn(state, min(grain, epochs - s))
+            k = min(grain, epochs - s)
+            with span("repro.islands.superstep", epoch=s, evaluations=n_i * (
+                    k * steps_per_epoch * lam + (s == 0) * cfg.mu)):
+                state, record = fn(state, k)
             fn = donating
-            if checkpoint_fn is not None:
+            if harvest:
                 if pending is not None:
-                    checkpoint_fn(pending)   # flush overlaps device compute
-                pending = host_snapshot(state)
+                    _flush(*pending)      # flush overlaps device compute
+                pending = host_snapshot(state), record
         if pending is not None:
-            checkpoint_fn(pending)
+            _flush(*pending)
         return state
 
     evolve = jax.jit(make_evolve(cfg, eval_fn, lam=lam,
@@ -394,8 +455,7 @@ def run_islands(cfg: NSGA2Config, eval_fn, key, *, n_islands: int,
         # silently skipping the boundary reseed.
         state = IslandState(seeded if e + 1 < epochs else evolved,
                             archive, jnp.int32(e + 1), jnp.int32(total))
-        if checkpoint_fn is not None:
-            checkpoint_fn(state)
+        _flush(state, None)
         if e + 1 < epochs:
             evolved = next_evolved
     return state

@@ -2,7 +2,9 @@ from repro.explore.sampling import (Sampling, GridSampling, UniformSampling,  # 
                                     LHSSampling, SobolSampling, SeedSampling,
                                     CrossSampling)
 from repro.explore.statistics import StatisticTask, median, mean, std, q  # noqa
-from repro.explore.replication import Replicate, replicated, replicated_batch  # noqa
+from repro.explore.replication import (Replicate, replicated,  # noqa
+                                      replicated_batch,
+                                      replicated_batch_state)
 from repro.explore.surrogate import (SurrogateConfig, SurrogateExplorer,  # noqa
                                      SurrogateResult, run_surrogate)
 from repro.explore.moacq import (MOSurrogateConfig, MOSurrogateExplorer,  # noqa

@@ -58,14 +58,26 @@ def replicated_batch(batch_eval_fn: Callable, n_replicates: int,
     """Same but for natively-batched eval fns (keys (L,), genomes (L, D)) ->
     (L, M): replicates become extra lanes, reduced after the flat call.
     This is the high-throughput path for the ants simulator."""
+    with_lanes = replicated_batch_state(
+        lambda keys, genomes: (batch_eval_fn(keys, genomes), None),
+        n_replicates, reducer)
+    return lambda keys, genomes: with_lanes(keys, genomes)[0]
+
+
+def replicated_batch_state(batch_eval_fn: Callable, n_replicates: int,
+                           reducer: Callable = jnp.median) -> Callable:
+    """``replicated_batch`` for eval fns that return ``(objectives,
+    lanes)``: returns the reduced (N, M) objectives and ``lanes`` as the
+    flat call left them, lane ``i * n_replicates + r`` being replicate
+    ``r`` of genome ``i``."""
 
     def replicated_eval(keys, genomes):
         n, d = genomes.shape
         rkeys = jax.vmap(lambda k: jax.random.split(k, n_replicates))(keys)
         flat_keys = rkeys.reshape(n * n_replicates)
         flat_genomes = jnp.repeat(genomes, n_replicates, axis=0)
-        objs = batch_eval_fn(flat_keys, flat_genomes)
+        objs, lanes = batch_eval_fn(flat_keys, flat_genomes)
         objs = objs.reshape(n, n_replicates, -1)
-        return reducer(objs, axis=1)
+        return reducer(objs, axis=1), lanes
 
     return replicated_eval

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import checkpoint
-from repro.ants import simulate_batch
+from repro.ants import simulate_batch, simulate_batch_state
 from repro.configs.ants_netlogo import BOUNDS, CONFIG, REDUCED
 from repro.core import (Context, EnvironmentPool, FaultSpec,
                         LocalEnvironment, SavePopulationHook,
@@ -30,7 +30,8 @@ from repro.core.scheduler import RunRecord, TaskRecord, _utcnow
 from repro.evolution import (NSGA2Config, ga, init_island_state, make_epoch,
                              pareto_front, run_islands)
 from repro.explore import (MOSurrogateConfig, SurrogateConfig,
-                           replicated_batch, run_surrogate, run_surrogate_mo)
+                           replicated_batch, replicated_batch_state,
+                           run_surrogate, run_surrogate_mo)
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import init_distributed, make_host_mesh, \
     make_island_mesh
@@ -74,12 +75,21 @@ def calibrate(*, reduced: bool = True, n_islands: int = 8, mu: int = 16,
               pipeline: bool = False, reseed_frac: float = 0.5,
               epochs_per_superstep: int = 0, init_population: int = 0,
               init_chunk: int = 2048, fault_rate: float = 0.0,
-              pool_devices: int = 0, printer=print):
+              pool_devices: int = 0, printer=print, epoch_hook=None):
+    """Island NSGA-II calibration of the ants model. ``epoch_hook(state,
+    record)``, where given, sees every committed epoch after its checkpoint:
+    the host snapshot and the superstep's ``EpochRecord`` (each island's
+    last GA step with every child lane's final ``AntsState``, and the
+    islands the archive merged), on the device."""
     ants_cfg = REDUCED if reduced else CONFIG
     ga_cfg = NSGA2Config(mu=mu, genome_dim=2, bounds=BOUNDS, n_objectives=3)
     eval_fn = replicated_batch(
         lambda keys, genomes: simulate_batch(ants_cfg, keys, genomes[:, 0],
                                              genomes[:, 1]),
+        replicates)
+    island_eval = replicated_batch_state(
+        lambda keys, genomes: simulate_batch_state(
+            ants_cfg, keys, genomes[:, 0], genomes[:, 1]),
         replicates)
     mesh = mesh or make_host_mesh()
     os.makedirs(out_dir, exist_ok=True)
@@ -182,12 +192,12 @@ def calibrate(*, reduced: bool = True, n_islands: int = 8, mu: int = 16,
     t0 = time.time()
     with shd.use_mesh(mesh):
         state = run_islands(
-            ga_cfg, eval_fn, jax.random.key(0), n_islands=n_islands, lam=lam,
-            steps_per_epoch=steps_per_epoch, epochs=epochs,
+            ga_cfg, island_eval, jax.random.key(0), n_islands=n_islands,
+            lam=lam, steps_per_epoch=steps_per_epoch, epochs=epochs,
             archive_size=archive_size, checkpoint_fn=on_epoch,
             merge_top_k=min(merge_top_k, mu), reseed_frac=reseed_frac,
             pipeline=pipeline, epochs_per_superstep=epochs_per_superstep,
-            start_state=start)
+            start_state=start, epoch_hook=epoch_hook)
     dt = time.time() - t0
     evals = int(state.total_evaluations)
     printer(f"[explore] done: {evals} evaluations in {dt:.1f}s "

@@ -88,10 +88,10 @@ def test_superstep_runs_donated_under_transfer_guard():
                                   archive_size=64)
         sstep = jax.jit(make_superstep(cfg, _zdt, lam=8, steps_per_epoch=1),
                         static_argnums=1, donate_argnums=0)
-        state = sstep(state, 3)
+        state, _ = sstep(state, 3)
         jax.block_until_ready(state.archive.objectives)
         with jax.transfer_guard("disallow"):
-            state = sstep(state, 3)
+            state, _ = sstep(state, 3)
             jax.block_until_ready(state.archive.objectives)
     assert int(state.epoch) == 6
 
@@ -108,8 +108,8 @@ def test_superstep_equals_epoch_loop_bit_exact():
     ref = state
     for _ in range(4):
         ref = epoch(ref)
-    got = jax.jit(make_superstep(cfg, _zdt, lam=8, steps_per_epoch=2),
-                  static_argnums=1)(state, 4)
+    got, _ = jax.jit(make_superstep(cfg, _zdt, lam=8, steps_per_epoch=2),
+                     static_argnums=1)(state, 4)
     np.testing.assert_array_equal(np.asarray(got.archive.objectives),
                                   np.asarray(ref.archive.objectives))
     np.testing.assert_array_equal(np.asarray(got.islands.genomes),
@@ -242,9 +242,9 @@ _EPOCH_DIGEST = """
                                   archive_size=96)
         sstep = jax.jit(make_superstep(cfg, zdt, lam=8, steps_per_epoch=2),
                         static_argnums=1, donate_argnums=0)
-        state = sstep(state, 4)
+        state, _ = sstep(state, 4)
         with jax.transfer_guard("disallow"):
-            state = sstep(state, 2)
+            state, _ = sstep(state, 2)
             jax.block_until_ready(state.archive.objectives)
     h = hashlib.sha256()
     h.update(np.asarray(state.archive.objectives).tobytes())

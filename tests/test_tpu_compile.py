@@ -13,6 +13,12 @@ process may load the TPU library at a time, and every test worker imports
 this file. All compile tests live in this one file so that one worker holds
 the library.
 """
+import base64
+import hashlib
+import os
+import re
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -154,3 +160,96 @@ def test_island_merge_compiles_on_a_four_chip_mesh(four_chips, monkeypatch):
             place(st.archive, rep), place(st.islands, isl)).compile(
             ).as_text()
     assert "tpu_custom_call" in text
+
+
+def test_island_superstep_compiles_with_its_record_at_config(one_chip,
+                                                            monkeypatch):
+    """The island cell's superstep at CONFIG (8 islands x 16 children x 5
+    replicates, one GA step an epoch), with the record it returns beside
+    the state: every child lane's final ants state."""
+    from repro.ants import simulate_batch_state
+    from repro.configs.ants_netlogo import BOUNDS, CONFIG
+    from repro.evolution import NSGA2Config, init_island_state, \
+        make_superstep
+    from repro.explore import replicated_batch_state
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the chip's route
+    cfg = NSGA2Config(mu=16, genome_dim=2, bounds=BOUNDS, n_objectives=3)
+    st = jax.eval_shape(lambda k: init_island_state(
+        cfg, k, n_islands=8, archive_size=256), jax.random.key(0))
+    evaluate = replicated_batch_state(
+        lambda k, g: simulate_batch_state(CONFIG, k, g[:, 0], g[:, 1]), 5)
+    superstep = make_superstep(cfg, evaluate, lam=16, steps_per_epoch=1,
+                               merge_top_k=8)
+    _, record = jax.eval_shape(lambda s: superstep(s, 1), st)
+    assert record.step.lanes.chem.shape == (8, 80, 72, 72)
+    args = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        x.shape, x.dtype, sharding=one_chip), st)
+    text = jax.jit(superstep, static_argnums=1, donate_argnums=0).lower(
+        args, 1).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+# The streaming cells' chunk program, compiled for a described v5e, as
+# ``operations_digest`` reduces it, on the tree before
+# ``simulate_batch_state`` existed (commit d596e63): the program the cells
+# time must not change with the entry that returns the final state.
+CHUNK_PROGRAM_DIGESTS = {
+    64: "3785a104359c1bf5302a842038b503ea3f3d734338cd92112428e816d92fea2b",
+    1: "3defac3f6ee77ff66361b23cb970d2b006d9429b2efd87a21e631cb9bba9216c",
+}
+_SOURCE_TABLES = ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames")
+_METADATA = re.compile(r",? metadata=\{[^}]*\}")
+_KERNEL_BODY = re.compile(r'"body":"([A-Za-z0-9+/=]*)"')
+
+
+def _kernel_without_locations(match):
+    """A Pallas kernel's serialized body as the sha256 of its MLIR printed
+    without source locations (they name the callers' files and
+    functions)."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    module = ir.Module.parse(base64.b64decode(match.group(1)), context=ctx)
+    asm = module.operation.get_asm(enable_debug_info=False)
+    return '"body":"sha256:%s"' % hashlib.sha256(asm.encode()).hexdigest()
+
+
+def operations_digest(text: str) -> str:
+    """sha256 of a compiled module's instructions with the source tables,
+    every op's metadata and the kernels' source locations left out."""
+    blocks = [b for b in text.split("\n\n")
+              if not b.startswith(_SOURCE_TABLES)]
+    ops = _KERNEL_BODY.sub(_kernel_without_locations,
+                           _METADATA.sub("", "\n\n".join(blocks)))
+    return hashlib.sha256(ops.encode()).hexdigest()
+
+
+@pytest.mark.parametrize("individuals", [64, 1])
+def test_the_streaming_cells_chunk_program_is_unchanged(one_chip,
+                                                        individuals,
+                                                        monkeypatch):
+    """``egi_init.chunk64`` and ``egi_init.single``'s chunk program, built
+    as their entry builds it, compiles to the same operations as before."""
+    import json
+
+    from repro.kernels import ops
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "perfbench")
+    monkeypatch.syspath_prepend(bench)
+    from entries import streaming_init
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # the chip's route
+    with open(os.path.join(bench, "configs", "ants_egi_init.json")) as f:
+        config = json.load(f)
+    keys = jax.eval_shape(
+        lambda: jax.random.split(jax.random.key(0), individuals))
+    text = jax.jit(streaming_init.make_eval(config)).lower(
+        jax.ShapeDtypeStruct(keys.shape, keys.dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((individuals, 2), F32, sharding=one_chip)
+    ).compile().as_text()
+    assert operations_digest(text) == CHUNK_PROGRAM_DIGESTS[individuals]

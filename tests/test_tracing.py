@@ -121,3 +121,66 @@ def test_a_chunk_records_its_steps_in_order_and_returns_as_before(tmp_path):
     assert len({s[3] for s in spans}) == 1
     ends = [s[2] for s in spans]
     assert all(a <= b for a, b in zip(ends, [s[1] for s in spans][1:]))
+
+
+ISLAND_STAGES = ("islands.init_eval", "islands.select", "islands.merge",
+                 "islands.reseed")
+
+
+def _island_eval(cfg, replicates=2):
+    from repro.ants import simulate_batch_state
+    from repro.explore import replicated_batch_state
+    return replicated_batch_state(
+        lambda keys, genomes: simulate_batch_state(cfg, keys, genomes[:, 0],
+                                                   genomes[:, 1]),
+        replicates)
+
+
+def test_the_compiled_superstep_names_the_island_stages_and_tick_phases():
+    from repro.evolution import init_island_state, make_superstep
+    ga_cfg = NSGA2Config(mu=4, genome_dim=2, bounds=((0., 99.),) * 2)
+    state = init_island_state(ga_cfg, jax.random.key(0), n_islands=2,
+                              archive_size=8)
+    superstep = make_superstep(ga_cfg, _island_eval(CFG), lam=4,
+                               steps_per_epoch=1, merge_top_k=2)
+    text = jax.jit(superstep, static_argnums=1).lower(state, 1).compile(
+        ).as_text()
+    names = re.findall(r'op_name="([^"]*)"', text)
+    # a stage is an op_name component (``state.islands.genomes`` is an
+    # argument's path)
+    found = {m for n in names
+             for m in re.findall(r"(?<![\w.])islands\.[a-z_]+", n)}
+    assert found == set(ISLAND_STAGES)
+    ticks = {m for n in names for m in re.findall(r"ants\.[a-z]+", n)}
+    assert ticks == set(PHASES)
+    # the initial evaluation's ticks carry its stage and their phase
+    assert any("islands.init_eval" in n and "ants.sense" in n for n in names)
+
+
+def test_a_calibration_records_a_superstep_and_a_checkpoint_each_epoch(
+        tmp_path, monkeypatch):
+    from repro.launch import explore
+    monkeypatch.setattr(explore, "CONFIG", CFG)
+    seen = []
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0          # no per-call Python events
+    jax.profiler.start_trace(str(tmp_path / "trace"), profiler_options=opts)
+    try:
+        explore.calibrate(reduced=False, n_islands=2, mu=4, lam=4,
+                          steps_per_epoch=1, epochs=2, replicates=2,
+                          archive_size=8, merge_top_k=2,
+                          out_dir=str(tmp_path / "out"),
+                          printer=lambda m: None,
+                          epoch_hook=lambda s, r: seen.append(
+                              (int(s.epoch), r.step.lanes.chem.shape)))
+    finally:
+        jax.profiler.stop_trace()
+    assert seen == [(1, (2, 8, 16, 16)), (2, (2, 8, 16, 16))]
+    spans = _spans(str(tmp_path / "trace"))
+    steps = [s for s in spans if s[0] == "repro.islands.superstep"]
+    marks = [s for s in spans if s[0] == "repro.islands.checkpoint"]
+    assert [(s[4]["epoch"], s[4]["evaluations"]) for s in steps] == [
+        ("0", "16"), ("1", "8")]
+    assert [s[4]["epoch"] for s in marks] == ["1", "2"]
+    # epoch 1's checkpoint is written while epoch 2's superstep is queued
+    assert steps[1][1] < marks[0][1]
