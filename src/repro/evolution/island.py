@@ -172,15 +172,27 @@ def make_recorded_evolve(cfg: NSGA2Config, eval_fn: Callable, *, lam: int,
         with jax.named_scope("islands.init_eval"):
             return ga.evaluate_initial(cfg, s, eval_fn)
 
-    def evolve_island(istate: ga.GAState):
-        # first epoch: islands arrive unevaluated -> evaluate initial pop
-        istate = jax.lax.cond(istate.valid.any(), lambda s: s, initial,
-                              istate)
-        return _repeat(step, istate, steps_per_epoch)
+    def maybe_initial(istate: ga.GAState):
+        return jax.lax.cond(istate.valid.any(), lambda s: s, initial,
+                            istate)
+
+    def evolve_local(islands: ga.GAState):
+        # Islands arrive unevaluated in the first epoch only. Under vmap a
+        # per-island cond is a select that runs the initial evaluation on
+        # every island, so the question is asked once, over this device's
+        # islands: when all are evaluated (every epoch after the first) the
+        # initial evaluation is not executed at all; otherwise the
+        # per-island cond evaluates the fresh ones and leaves the rest as
+        # they were.
+        islands = jax.lax.cond(islands.valid.any(axis=1).all(),
+                               lambda s: s, jax.vmap(maybe_initial),
+                               islands)
+        return jax.vmap(lambda s: _repeat(step, s, steps_per_epoch))(
+            islands)
 
     def evolve(islands: ga.GAState):
         islands = _constrain_islands(islands)
-        islands, record = _per_island(jax.vmap(evolve_island))(islands)
+        islands, record = _per_island(evolve_local)(islands)
         return _constrain_islands(islands), record
 
     return evolve

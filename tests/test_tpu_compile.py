@@ -166,7 +166,10 @@ def test_island_superstep_compiles_with_its_record_at_config(one_chip,
                                                             monkeypatch):
     """The island cell's superstep at CONFIG (8 islands x 16 children x 5
     replicates, one GA step an epoch), with the record it returns beside
-    the state: every child lane's final ants state."""
+    the state: every child lane's final ants state. The islands' initial
+    evaluation sits behind a ``conditional``: its diffusion kernel is in
+    a branch computation, never in the straight-line program, so an
+    epoch whose islands are all evaluated does not run it."""
     from repro.ants import simulate_batch_state
     from repro.configs.ants_netlogo import BOUNDS, CONFIG
     from repro.evolution import NSGA2Config, init_island_state, \
@@ -189,6 +192,56 @@ def test_island_superstep_compiles_with_its_record_at_config(one_chip,
     text = jax.jit(superstep, static_argnums=1, donate_argnums=0).lower(
         args, 1).compile().as_text()
     assert "tpu_custom_call" in text
+    straight, branched, kernels = _computation_reach(text)
+    init_eval = {c for c, names in kernels.items()
+                 if any("islands.init_eval" in n and "diffuse_evaporate" in n
+                        for n in names)}
+    assert branched, "no conditional in the compiled superstep"
+    assert init_eval and init_eval <= branched
+    assert not init_eval & straight
+
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.-]+) ")
+_CALLED = re.compile(r"\b(calls|to_apply|body|condition|true_computation|"
+                     r"false_computation)=%([\w.-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_BRANCH_KEYS = ("true_computation", "false_computation")
+_KERNEL_OP = re.compile(r'custom_call_target="tpu_custom_call".*?'
+                        r'op_name="([^"]*)"')
+
+
+def _computation_reach(text: str):
+    """(computations the entry reaches without entering a conditional's
+    branch, computations reached from some branch, computation -> op_names
+    of its Pallas kernels) of a compiled module's text."""
+    edges, branch_edges, kernels, entry = {}, {}, {}, None
+    for block in text.split("\n\n"):
+        head = _COMPUTATION.match(block)
+        if not head:
+            continue
+        name = head.group(1)
+        entry = name if block.startswith("ENTRY") else entry
+        called = _CALLED.findall(block)
+        edges[name] = {c for k, c in called if k not in _BRANCH_KEYS}
+        branch_edges[name] = {c for k, c in called if k in _BRANCH_KEYS} | set(
+            re.findall(r"%([\w.-]+)", " ".join(_BRANCHES.findall(block))))
+        kernels[name] = _KERNEL_OP.findall(block)
+
+    def reach(roots, follow_branches):
+        seen, todo = set(), list(roots)
+        while todo:
+            c = todo.pop()
+            if c in seen:
+                continue
+            seen.add(c)
+            todo += edges.get(c, ())
+            if follow_branches:
+                todo += branch_edges.get(c, ())
+        return seen
+
+    straight = reach([entry], False)
+    branched = reach(set().union(*branch_edges.values()), True)
+    return straight, branched, kernels
 
 
 # The streaming cells' chunk program, compiled for a described v5e, as
