@@ -4,8 +4,7 @@ from __future__ import annotations
 import importlib
 from typing import Dict, Tuple
 
-from repro.configs.base import (ModelConfig, ShapeConfig, SHAPES,
-                                SHAPES_BY_NAME, cells_for)
+from repro.configs.base import ModelConfig
 
 # arch id -> module path (ids are the assignment's exact spellings)
 _ARCH_MODULES: Dict[str, str] = {
@@ -31,17 +30,4 @@ def get_config(arch_id: str, reduced: bool = False) -> ModelConfig:
     return mod.REDUCED if reduced else mod.CONFIG
 
 
-def get_shape(shape_name: str) -> ShapeConfig:
-    return SHAPES_BY_NAME[shape_name]
-
-
-def all_cells():
-    """Yield (arch_id, ModelConfig, ShapeConfig, status) for the 40 cells."""
-    for arch_id in ARCH_IDS:
-        cfg = get_config(arch_id)
-        for _, shape, status in cells_for(cfg):
-            yield arch_id, cfg, shape, status
-
-
-__all__ = ["ModelConfig", "ShapeConfig", "SHAPES", "SHAPES_BY_NAME",
-           "ARCH_IDS", "get_config", "get_shape", "all_cells", "cells_for"]
+__all__ = ["ModelConfig", "ARCH_IDS", "get_config"]

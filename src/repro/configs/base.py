@@ -1,19 +1,13 @@
-"""Config system: model architecture configs + input-shape registry.
+"""Config system: model architecture configs.
 
 Every assigned architecture gets one ``<id>.py`` module exporting ``CONFIG``
 (a :class:`ModelConfig` with the exact published numbers) and optionally
 ``REDUCED`` (a small same-family config used by CPU smoke tests).
-
-Shapes come from the assignment:
-  train_4k     seq_len=4096    global_batch=256   (training)
-  prefill_32k  seq_len=32768   global_batch=32    (inference-prefill)
-  decode_32k   seq_len=32768   global_batch=128   (inference-decode, 1 new tok)
-  long_500k    seq_len=524288  global_batch=1     (long-context decode)
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 # ---------------------------------------------------------------------------
 # Layer kinds used to describe heterogeneous stacks (Jamba etc.).
@@ -86,27 +80,17 @@ class ModelConfig:
     tie_embeddings: bool = False
     act: str = "silu"                   # silu (SwiGLU) | gelu (plain MLP)
     qk_norm: bool = False               # Chameleon-style qk RMSNorm
-    # --- numerics / parallelism knobs (hillclimb surface) ---------------------
+    # --- numerics / parallelism knobs ----------------------------------------
     dtype: str = "bfloat16"
     remat_policy: str = "dots"          # none | dots | full
-    # grad-accum microbatches per shape name (memory knob); default 1
-    microbatches: Tuple[Tuple[str, int], ...] = ()
     fsdp: bool = False                  # shard params/opt over data axis too
     use_flash_kernel: bool = True       # Pallas flash attention for prefill
     # schedule: wsd (MiniCPM) | cosine
     schedule: str = "cosine"
-    # skip long_500k (quadratic attention)? set for pure full-attn archs
-    supports_long_context: bool = False
     # embedding tables are padded up to a multiple of this so the vocab dim
     # shards evenly on any production mesh axis (padded logits are masked);
     # the standard production trick for "odd" vocabs like minicpm's 122753.
     vocab_multiple: int = 1
-    # Dry-run/roofline knobs: XLA's cost_analysis counts a while-loop body
-    # ONCE (see tests/test_roofline.py calibration), so the dry-run compiles
-    # with the layer scan unrolled and the CE token loop in a single chunk to
-    # make HLO FLOPs/bytes exact. Execution configs keep the scans.
-    unroll_blocks: bool = False
-    ce_chunk: int = 1024
     # per-arch logical-rule overrides for the sharding resolver, e.g. the
     # pure-DP mapping for small models whose head counts don't divide the
     # model axis: (("batch", (("data","model"),)), ("__no_tp_fallback__", 1))
@@ -139,12 +123,6 @@ class ModelConfig:
     def n_blocks(self) -> int:
         """Number of repeats of the layer pattern (scan length)."""
         return self.n_layers // len(self.pattern)
-
-    def microbatches_for(self, shape_name: str) -> int:
-        for k, v in self.microbatches:
-            if k == shape_name:
-                return v
-        return 1
 
     # ---- parameter counting (for MODEL_FLOPS = 6*N*D roofline term) --------
     def param_counts(self) -> Tuple[int, int]:
@@ -211,31 +189,3 @@ class ModelConfig:
             active += enc + xattn
         return total, active
 
-
-@dataclasses.dataclass(frozen=True)
-class ShapeConfig:
-    name: str
-    seq_len: int
-    global_batch: int
-    kind: str                           # train | prefill | decode
-
-
-SHAPES: Tuple[ShapeConfig, ...] = (
-    ShapeConfig("train_4k", 4096, 256, "train"),
-    ShapeConfig("prefill_32k", 32768, 32, "prefill"),
-    ShapeConfig("decode_32k", 32768, 128, "decode"),
-    ShapeConfig("long_500k", 524288, 1, "decode"),
-)
-
-SHAPES_BY_NAME = {s.name: s for s in SHAPES}
-
-
-def cells_for(cfg: ModelConfig) -> Sequence[Tuple[ModelConfig, ShapeConfig, str]]:
-    """All (cfg, shape, status) cells; status is 'run' or a skip reason."""
-    out = []
-    for s in SHAPES:
-        if s.name == "long_500k" and not cfg.supports_long_context:
-            out.append((cfg, s, "skip: quadratic full attention at 512k"))
-        else:
-            out.append((cfg, s, "run"))
-    return out

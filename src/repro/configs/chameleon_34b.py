@@ -1,8 +1,7 @@
 """Chameleon-34B [arXiv:2405.09818] — early-fusion VLM, dense backbone.
 
 Image VQ tokens share the text vocabulary (early fusion), so the backbone
-consumes plain token ids; the VQ image tokenizer is the stubbed frontend
-(input_specs() provides token ids directly).
+consumes plain token ids; the VQ image tokenizer is the stubbed frontend.
 """
 from repro.configs.base import ModelConfig
 
@@ -22,8 +21,6 @@ CONFIG = ModelConfig(
     qk_norm=True,
     fsdp=True,
     remat_policy="full",
-    microbatches=(("train_4k", 16),),
-    supports_long_context=False,
     notes="Chameleon's qk-norm is included (training-stability feature the "
           "paper highlights). Frontend (VQ-VAE tokenizer) is a stub.",
 )

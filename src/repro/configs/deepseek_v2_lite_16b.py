@@ -30,10 +30,8 @@ CONFIG = ModelConfig(
     act="silu",
     fsdp=True,
     remat_policy="dots",
-    microbatches=(("train_4k", 4),),
-    supports_long_context=False,
     notes="MLA compresses the KV cache to kv_lora_rank+rope dims per token; "
-          "still quadratic attention -> long_500k skipped.",
+          "attention is still quadratic in sequence length.",
 )
 
 REDUCED = ModelConfig(

@@ -17,8 +17,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     fsdp=True,
     remat_policy="dots",
-    microbatches=(("train_4k", 4),),
-    supports_long_context=False,
     notes="Granite's logit/residual/embedding multipliers are folded into "
           "init scales (simplification; does not change sharding/roofline).",
 )

@@ -23,8 +23,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     fsdp=False,
     remat_policy="none",
-    microbatches=(("train_4k", 2),),
-    supports_long_context=False,
 )
 
 REDUCED = ModelConfig(

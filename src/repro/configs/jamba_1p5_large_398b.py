@@ -32,9 +32,7 @@ CONFIG = ModelConfig(
     act="silu",
     fsdp=True,
     remat_policy="full",
-    microbatches=(("train_4k", 16),),
-    supports_long_context=True,
-    notes="long_500k runs: only 9/72 layers are attention; their KV cache is "
+    notes="only 9/72 layers are attention; their KV cache is "
           "sharded along sequence on the model axis.",
 )
 
@@ -55,5 +53,4 @@ REDUCED = ModelConfig(
                   expert_d_ff=128, shared_d_ff=0),
     ssm=SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=32,
                   chunk_size=32, n_groups=1),
-    supports_long_context=True,
 )

@@ -1,7 +1,7 @@
 """Mamba-2 2.7B [arXiv:2405.21060] — attention-free SSD (state-space duality).
 
-64 pure Mamba-2 blocks (no MLP), d_state=128. Supports long_500k: the decode
-state is O(1) in sequence length.
+64 pure Mamba-2 blocks (no MLP), d_state=128. The decode state is O(1) in
+sequence length.
 """
 from repro.configs.base import ModelConfig, SSMConfig, SSM, NO_FF
 
@@ -21,8 +21,6 @@ CONFIG = ModelConfig(
     act="silu",
     fsdp=True,
     remat_policy="dots",
-    microbatches=(("train_4k", 8),),
-    supports_long_context=True,
     notes="vocab 50280 padded to 51200 (vocab_multiple=2048) for even sharding.",
 )
 
@@ -38,5 +36,4 @@ REDUCED = ModelConfig(
     layer_pattern=((SSM, NO_FF),),
     ssm=SSMConfig(d_state=16, d_conv=4, expand=2, head_dim=32,
                   chunk_size=32, n_groups=1),
-    supports_long_context=True,
 )

@@ -18,12 +18,10 @@ CONFIG = ModelConfig(
     tie_embeddings=True,       # MiniCPM ties embeddings
     fsdp=True,
     remat_policy="dots",
-    microbatches=(("train_4k", 4),),
     # §Perf hillclimb: 36 heads do not divide the 16-way model axis ->
     # attention replicates. Sequence-parallel attention compute recovers it:
     # 4.2x fewer FLOPs/dev (useful-FLOPs fraction 17% -> 73%).
     attn_seq_shard=True,
-    supports_long_context=False,
     notes="vocab 122753 is padded to 122880 (vocab_multiple=2048) so the "
           "embedding shards evenly on the model axis; padded logits masked.",
 )

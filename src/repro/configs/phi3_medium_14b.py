@@ -16,8 +16,6 @@ CONFIG = ModelConfig(
     act="silu",
     fsdp=True,
     remat_policy="dots",
-    microbatches=(("train_4k", 8),),
-    supports_long_context=False,
 )
 
 REDUCED = ModelConfig(

@@ -17,7 +17,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     fsdp=True,
     remat_policy="none",
-    supports_long_context=False,
     # §Perf hillclimb: 9 heads / 3 kv heads do not divide the 16-way model
     # axis, so TP replicates attention 16x. A 135M model needs no TP: map
     # batch over (data x model) = 256-way pure DP (+FSDP for optimizer

@@ -1,7 +1,7 @@
 """Whisper-base [arXiv:2212.04356] — encoder-decoder audio backbone.
 
 6 encoder + 6 decoder layers, d_model=512, 8 heads, GELU MLP. The conv audio
-frontend is a STUB: input_specs() provides precomputed frame embeddings of
+frontend is a STUB: the encoder takes precomputed frame embeddings of
 shape (batch, 1500, 512) (30 s of audio after the conv downsampler).
 Decode shapes exercise the decoder (self-attn KV cache + cross-attn cache).
 """
@@ -26,7 +26,6 @@ CONFIG = ModelConfig(
     tie_embeddings=True,
     fsdp=False,
     remat_policy="none",
-    supports_long_context=False,
     notes="Whisper uses learned absolute positions; we keep RoPE for the "
           "decoder and sinusoidal for the encoder (backbone-equivalent "
           "adaptation, noted per DESIGN.md). vocab 51865 padded to 53248 for even sharding.",

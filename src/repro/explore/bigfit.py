@@ -14,9 +14,8 @@ path stays byte-for-byte the code it was):
   quantity is (m,) or (m, m), so after the one O(n m^2) cold fit a tell
   round appends with a rank-q update of the RUNNING sufficient statistics
   (A A^T, A y, A 1, count/sum/sq/min) and one (m, m) refactorization:
-  O(m^2 q + m^3), independent of n. Sub-second at N=50k (benchmarks:
-  surrogate_tell_50k). The (m, n) cross-covariance solve runs through the
-  blocked triangular-solve engine (kernels/ops.tri_solve).
+  O(m^2 q + m^3), independent of n. The (m, n) cross-covariance solve runs
+  through the blocked triangular-solve engine (kernels/ops.tri_solve).
 - **Local ensemble** (``fit_ensemble``): kd-style alternating-dimension
   median splits partition history into E equal cells of
   ``cfg.expert_size``; one exact GP per cell (vmapped factorization), and

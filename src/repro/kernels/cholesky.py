@@ -7,8 +7,8 @@ CPU and a single fused op elsewhere; at archive scale (N in the thousands,
 once per lengthscale grid point per round) it is elementwise-bound and
 serial. The blocked factorization spends its n^3/3 flops in (block, block)
 tile dots instead — MXU work on TPU, gemm-bound on CPU via the jitted
-oracle route — and on this host runs the 4096-point lengthscale grid
-~2-4x faster than the vmapped LAPACK path (benchmarks: gp_chol_4096).
+oracle route — and on the CPU runs the 4096-point lengthscale grid
+faster than the vmapped LAPACK path.
 
 Three kernels per step k of the right-looking schedule:
 

@@ -4,9 +4,9 @@ On TPU (``on_tpu()``) every wrapper runs its compiled Pallas kernel; that is
 the only route there, and interpret mode and the jnp reference are never
 reached. On the CPU, where the tests run, a wrapper runs the kernel in
 Pallas interpret mode while its grid is small and the jitted jnp reference
-from kernels/ref.py beyond that (interpret cost grows with the grid);
-dry-run lowering always takes the reference. The references are the
-oracles the tests and chip_smoke.py hold the kernels to (``ref.TOL``).
+from kernels/ref.py beyond that (interpret cost grows with the grid). The
+references are the oracles the tests and chip_smoke.py hold the kernels to
+(``ref.TOL``).
 """
 from __future__ import annotations
 
@@ -46,21 +46,8 @@ def flash_available(q, k, *, block_q=512, block_k=512) -> bool:
         bq, bk = min(block_q, s), min(block_k, s)
         if s % bq or s % bk:
             return False
-        return b * h * (s // bq) * (s // bk) <= _INTERPRET_GRID_LIMIT \
-            and not _in_dryrun()
+        return b * h * (s // bq) * (s // bk) <= _INTERPRET_GRID_LIMIT
     return s % min(block_q, s) == 0 and s % min(block_k, s) == 0
-
-
-_DRYRUN = [False]
-
-
-def set_dryrun(flag: bool):
-    """Dry-run lowering must not inline interpret-mode kernels (HLO blowup)."""
-    _DRYRUN[0] = flag
-
-
-def _in_dryrun() -> bool:
-    return _DRYRUN[0]
 
 
 def flash_attention_gqa(q, k, v, *, causal=True, block_q=512, block_k=512):
@@ -104,7 +91,7 @@ def diffuse_evaporate(chem, rate, evap):
     n, w, _ = chem.shape
     if on_tpu():
         return _diffuse_pallas(chem, rate, evap, interpret=False)
-    if n <= _INTERPRET_GRID_LIMIT // 8 and not _in_dryrun():
+    if n <= _INTERPRET_GRID_LIMIT // 8:
         return _diffuse_pallas(chem, rate, evap, interpret=True)
     return ref.diffuse_evaporate_ref(chem, rate, evap)
 
@@ -137,8 +124,7 @@ def dominated_counts(objectives):
     n = objectives.shape[0]
     if on_tpu():
         return _dom_pallas(objectives, interpret=False)
-    if (-(-n // 512)) ** 2 <= _DOMINANCE_INTERPRET_STEPS and n >= 8 \
-            and not _in_dryrun():
+    if (-(-n // 512)) ** 2 <= _DOMINANCE_INTERPRET_STEPS and n >= 8:
         return _dom_pallas(objectives, interpret=True)
     return ref.dominated_counts_ref(objectives)
 
@@ -176,7 +162,7 @@ _gp_matrix_ref_jit = jax.jit(
 
 def _gp_use_interpret(n1: int, n2: int, block: int = 256) -> bool:
     steps = (-(-n1 // block)) * (-(-n2 // block))
-    return steps <= _GP_INTERPRET_STEPS and not _in_dryrun()
+    return steps <= _GP_INTERPRET_STEPS
 
 
 def gp_sqdist(x1, x2):
@@ -212,9 +198,9 @@ def gp_matrix(x1, x2, *, kind="matern52", lengthscale=0.2, variance=1.0):
 # dependent at the last bit (see the contract comment in ref.py), so these
 # wrappers take block= explicitly and default it to one pinned value.
 # The oracle route is the ENGINE route on CPU (gemm-bound left-looking
-# schedule, ~2-4x over the vmapped LAPACK grid at n=4096 — see
-# benchmarks gp_chol_4096); interpret mode exists to execute the actual
-# kernel program on small shapes so tests pin kernel == oracle.
+# schedule, faster there than the vmapped LAPACK grid at n=4096);
+# interpret mode exists to execute the actual kernel program on small
+# shapes so tests pin kernel == oracle.
 # The blocked grid must NOT be vmapped on CPU (measured pathological);
 # sweep lengthscale grids with a python loop under one jit instead.
 _CHOL_INTERPRET_STEPS = 64
@@ -279,8 +265,7 @@ def chol_factor(a, *, block=_CHOL_BLOCK):
     ap = _pad_identity(a, n_p)
     if on_tpu():
         return chol_blocked(ap, block=block, interpret=False)[:n, :n]
-    if _chol_steps(n_p // block) <= _CHOL_INTERPRET_STEPS \
-            and not _in_dryrun():
+    if _chol_steps(n_p // block) <= _CHOL_INTERPRET_STEPS:
         return chol_blocked(ap, block=block, interpret=True)[:n, :n]
     return _chol_blocked_ref_jit(ap, block)[:n, :n]
 
@@ -303,8 +288,7 @@ def gp_chol(x, *, kind="matern52", lengthscale=0.2, nugget=1e-4,
         return gp_chol_blocked(xp, n, kind=kind, lengthscale=lengthscale,
                                nugget=nugget, block=block,
                                interpret=False)[:n, :n]
-    if _chol_steps(n_p // block) <= _CHOL_INTERPRET_STEPS \
-            and not _in_dryrun():
+    if _chol_steps(n_p // block) <= _CHOL_INTERPRET_STEPS:
         return gp_chol_blocked(xp, n, kind=kind, lengthscale=lengthscale,
                                nugget=nugget, block=block,
                                interpret=True)[:n, :n]
@@ -332,7 +316,7 @@ def tri_solve(l, b, *, trans=False, block=_CHOL_BLOCK,
     if on_tpu():
         xs = tri_solve_blocked(lp, bp, trans=trans, block=block,
                                rhs_block=rhs_block, interpret=False)
-    elif steps <= _CHOL_INTERPRET_STEPS and not _in_dryrun():
+    elif steps <= _CHOL_INTERPRET_STEPS:
         xs = tri_solve_blocked(lp, bp, trans=trans, block=block,
                                rhs_block=rhs_block, interpret=True)
     else:
@@ -353,7 +337,7 @@ def dominance_pass(rows, cols=None, groups=None, groups_cols=None):
         return _dom_pass_pallas(rows, cols, groups, groups_cols,
                                 interpret=False)
     steps = (-(-ni // 256)) * (-(-nj // 256))
-    if steps <= _DOMINANCE_INTERPRET_STEPS and not _in_dryrun():
+    if steps <= _DOMINANCE_INTERPRET_STEPS:
         return _dom_pass_pallas(rows, cols, groups, groups_cols,
                                 interpret=True)
     return ref.dominance_pass_ref(rows, cols, groups, groups_cols)

@@ -1,7 +1,7 @@
 """The one place that turns on JAX's persistent compilation cache.
 
 Called from the entry points' ``main`` (``chip_smoke.py``,
-``repro.launch.explore``, ``benchmarks/run.py``), never at import, so
+``repro.launch.explore``, ``perfbench/run.py``), never at import, so
 tests and library users keep JAX's defaults.
 """
 from __future__ import annotations

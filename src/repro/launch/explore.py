@@ -27,7 +27,7 @@ from repro.core import (Context, EnvironmentPool, FaultSpec,
                         make_device_members)
 from repro.core.cache import hash_value
 from repro.core.scheduler import RunRecord, TaskRecord, _utcnow
-from repro.evolution import (NSGA2Config, ga, init_island_state, make_epoch,
+from repro.evolution import (NSGA2Config, ga, init_island_state,
                              pareto_front, run_islands)
 from repro.explore import (MOSurrogateConfig, SurrogateConfig,
                            replicated_batch, replicated_batch_state,

@@ -1,8 +1,7 @@
 """Production meshes.
 
 Defined as FUNCTIONS (not module constants) so importing this module never
-touches jax device state — required because only dryrun.py forces 512 host
-devices; tests and benches see 1 device.
+touches jax device state.
 """
 from __future__ import annotations
 
@@ -60,10 +59,3 @@ def init_distributed(*, coordinator: str = None, num_processes: int = None,
                                num_processes=num_processes,
                                process_id=process_id)
     return True
-
-
-# TPU v5e hardware constants (roofline denominators)
-PEAK_FLOPS_BF16 = 197e12        # per chip
-HBM_BW = 819e9                  # bytes/s per chip
-ICI_BW = 50e9                   # bytes/s per link (~3 links/chip on v5e 2D torus)
-HBM_BYTES = 16 * 2 ** 30        # 16 GB HBM per chip

@@ -72,8 +72,7 @@ def encode(cfg, params, frames):
         x = x + mlp_mod.mlp_apply(cfg, lp["ff"], h)
         return x, None
 
-    x, _ = jax.lax.scan(block, x, params["enc_blocks"],
-                        unroll=cfg.n_encoder_layers if cfg.unroll_blocks else 1)
+    x, _ = jax.lax.scan(block, x, params["enc_blocks"])
     return apply_norm(cfg, x, params["enc_norm"])
 
 
@@ -111,11 +110,9 @@ def decode_full(cfg, params, tokens, enc_out, caches=None, write_cache=False):
         def block_nc(x, lp):
             x, _ = block(x, (lp, {}))
             return x, None
-        x, _ = jax.lax.scan(block_nc, x, params["dec_blocks"],
-                            unroll=cfg.n_layers if cfg.unroll_blocks else 1)
+        x, _ = jax.lax.scan(block_nc, x, params["dec_blocks"])
     else:
-        x, caches = jax.lax.scan(block, x, (params["dec_blocks"], caches),
-                                 unroll=cfg.n_layers if cfg.unroll_blocks else 1)
+        x, caches = jax.lax.scan(block, x, (params["dec_blocks"], caches))
     x = apply_norm(cfg, x, params["final_norm"])
     return x, caches
 
@@ -163,7 +160,6 @@ def decode_step(cfg, params, token, positions, caches):
         return x, {"self": nc_self, "cross_k": bc["cross_k"],
                    "cross_v": bc["cross_v"]}
 
-    x, new_caches = jax.lax.scan(block, x, (params["dec_blocks"], caches),
-                                 unroll=cfg.n_layers if cfg.unroll_blocks else 1)
+    x, new_caches = jax.lax.scan(block, x, (params["dec_blocks"], caches))
     x = apply_norm(cfg, x, params["final_norm"])
     return logits_from_hidden(cfg, params, x), new_caches

@@ -6,8 +6,7 @@ exposes:
   loss(params, batch, rng)            -> (loss, metrics)
   prefill(params, batch)              -> (last_logits, caches)
   decode(params, batch, caches)       -> (logits, caches)
-  init_cache(batch, max_seq) / abstract_cache(...)
-  input_specs(shape)                  -> batch of ShapeDtypeStructs (dry-run)
+  init_cache(batch, max_seq)
 
 Cross-entropy is computed in token chunks under remat so the (tokens, vocab)
 logits tensor is never materialized at full size — with 100k+ vocabularies
@@ -16,13 +15,11 @@ this is the difference between fitting HBM and not.
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.models import encdec, transformer
 from repro.runtime.sharding import constrain
 
@@ -34,13 +31,12 @@ def _dtype(cfg):
 
 
 def chunked_softmax_xent(hidden, weight, targets, transpose_weight,
-                         z_loss_coef=1e-4, vocab_size=None,
-                         ce_chunk=CE_CHUNK):
+                         z_loss_coef=1e-4, vocab_size=None):
     """Mean CE over tokens, computed in chunks. hidden: (T,d) f-any,
     weight: (d,V) or (V,d) if transpose_weight; targets: (T,) int32.
     vocab_size: logical vocab — padded slots beyond it are masked out."""
     t, d = hidden.shape
-    chunk = min(ce_chunk, t)
+    chunk = min(CE_CHUNK, t)
     pad = (-t) % chunk
     if pad:
         hidden = jnp.pad(hidden, ((0, pad), (0, 0)))
@@ -124,7 +120,7 @@ class Model:
         b, s, d = hidden.shape
         ce, count = chunked_softmax_xent(
             hidden.reshape(b * s, d), weight, targets.reshape(b * s),
-            transpose, vocab_size=cfg.vocab_size, ce_chunk=cfg.ce_chunk)
+            transpose, vocab_size=cfg.vocab_size)
         loss = ce + aux["load_balance_loss"]
         metrics = {"ce": ce, "tokens": count,
                    "load_balance_loss": aux["load_balance_loss"],
@@ -137,17 +133,6 @@ class Model:
         if cfg.is_encoder_decoder:
             return encdec.init_cache(cfg, batch, max_seq, _dtype(cfg))
         return transformer.init_cache(cfg, batch, max_seq, _dtype(cfg))
-
-    def abstract_cache(self, batch, max_seq):
-        captured = {}
-
-        def only_cache():
-            c, ax = self.init_cache(batch, max_seq)
-            captured["axes"] = ax
-            return c
-
-        sds = jax.eval_shape(only_cache)
-        return sds, captured["axes"]
 
     def prefill(self, params, batch, caches):
         """Full-sequence prefill; returns (last-position logits, caches)."""
@@ -176,38 +161,6 @@ class Model:
             logits, caches = transformer.decode_step(
                 cfg, params, batch["token"], batch["positions"], caches)
         return logits.astype(jnp.float32), caches
-
-    # --------------------------------------------------------------- dry-run
-    def input_specs(self, shape: ShapeConfig) -> Dict[str, Any]:
-        """ShapeDtypeStruct stand-ins for every model input of this shape."""
-        cfg = self.cfg
-        b, s = shape.global_batch, shape.seq_len
-        i32 = jnp.int32
-        if shape.kind == "train":
-            specs = {"tokens": jax.ShapeDtypeStruct((b, s + 1), i32)}
-            if cfg.is_encoder_decoder:
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (b, cfg.encoder_seq_len, cfg.d_model), _dtype(cfg))
-            return specs
-        if shape.kind == "prefill":
-            specs = {"tokens": jax.ShapeDtypeStruct((b, s), i32)}
-            if cfg.is_encoder_decoder:
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (b, cfg.encoder_seq_len, cfg.d_model), _dtype(cfg))
-            return specs
-        # decode: one new token against a cache of length seq_len
-        return {"token": jax.ShapeDtypeStruct((b, 1), i32),
-                "positions": jax.ShapeDtypeStruct((b,), i32)}
-
-    def batch_axes(self, shape: ShapeConfig) -> Dict[str, Any]:
-        """Logical axes for each input-spec leaf (for in_shardings)."""
-        cfg = self.cfg
-        if shape.kind in ("train", "prefill"):
-            axes = {"tokens": ("batch", "seq")}
-            if cfg.is_encoder_decoder:
-                axes["frames"] = ("batch", "enc_seq", None)
-            return axes
-        return {"token": ("batch", None), "positions": ("batch",)}
 
 
 def build(cfg: ModelConfig) -> Model:

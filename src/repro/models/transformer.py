@@ -173,8 +173,7 @@ def forward(cfg, params, tokens, rng=None, caches=None, write_cache=False,
     if caches is None:
         caches = {f"layer{i}": {} for i in range(len(cfg.pattern))}
     (x, lb, dropped), new_caches = jax.lax.scan(
-        block_fn, init, (params["blocks"], caches),
-        unroll=cfg.n_blocks if cfg.unroll_blocks else 1)
+        block_fn, init, (params["blocks"], caches))
     x = apply_norm(cfg, x, params["final_norm"])
     aux = {"load_balance_loss": lb, "dropped_frac": dropped / cfg.n_layers}
     return x, aux, new_caches
@@ -255,8 +254,7 @@ def decode_step(cfg, params, token, positions, caches):
                 x = x + out
         return x, new_bc
 
-    x, new_caches = jax.lax.scan(block_fn, x, (params["blocks"], caches),
-                                 unroll=cfg.n_blocks if cfg.unroll_blocks else 1)
+    x, new_caches = jax.lax.scan(block_fn, x, (params["blocks"], caches))
     x = apply_norm(cfg, x, params["final_norm"])
     logits = logits_from_hidden(cfg, params, x)
     return logits, new_caches

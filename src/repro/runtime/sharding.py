@@ -50,7 +50,7 @@ _FSDP_MIN_SIZE = 1 << 20    # params smaller than 1M elements stay replicated
 
 def abstract_mesh(sizes: Sequence[int], names: Sequence[str]):
     """A device-free ``jax.sharding.AbstractMesh`` of the given axis sizes
-    (the resolver's tests and dry runs need no devices)."""
+    (the resolver's tests need no devices)."""
     from jax.sharding import AbstractMesh
     return AbstractMesh(tuple(sizes), tuple(names))
 

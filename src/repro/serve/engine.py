@@ -1,6 +1,6 @@
 """Batched serving engine: prefill a batch of prompts, then decode steps with
 greedy or temperature sampling. Designed so both phases are single jit-able
-functions (the dry-run lowers exactly these).
+functions.
 
 Continuous-batching-lite: finished sequences (EOS) are masked and their slots
 keep decoding pad tokens without affecting others; a host-side loop can swap

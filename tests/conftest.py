@@ -1,7 +1,7 @@
 import os
 
-# Tests run on the single host CPU device (the dry-run, and only the dry-run,
-# forces 512 devices — in its own subprocess).
+# Tests run on the host CPU; those that need several devices force them in
+# their own subprocesses.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax

@@ -455,6 +455,33 @@ def test_streaming_init_resumes_mid_population(tmp_path):
     assert modes.count("cache") == 5 and modes.count("stream") == 5
 
 
+def test_streaming_init_resumes_under_chaos(tmp_path):
+    """Kill and resume through the drivers' pool at 35 % injected job
+    failures: the resumed population is the failure-free one."""
+    from repro.evolution import ga
+    from repro.launch.explore import make_init_pool
+    cfg, eval_fn = _stream_setup()
+    clean = ga.evaluate_population_streaming(cfg, eval_fn, 0, n_total=640,
+                                             chunk=64)
+    ckpt = str(tmp_path / "init")
+
+    def run(**kw):
+        pool = make_init_pool(0.35, backoff_s=0.01, retries=12)
+        try:
+            return ga.evaluate_population_streaming(
+                cfg, eval_fn, 0, n_total=640, chunk=64, environment=pool,
+                checkpoint_dir=ckpt, **kw)
+        finally:
+            pool.shutdown()
+
+    part = run(stop_after_chunks=5)
+    assert part.interrupted and part.chunks_done >= 5
+    full = run()
+    assert not full.interrupted and full.resumed_chunks > 0
+    assert np.array_equal(clean.objectives, full.objectives)
+    assert np.array_equal(clean.genomes, full.genomes)
+
+
 def test_streaming_init_seeds_ga_state():
     import jax
     from repro.evolution import ga

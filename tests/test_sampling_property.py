@@ -153,8 +153,8 @@ if HAS_HYPOTHESIS:
     @settings(max_examples=30, deadline=None)
     @given(n=st.integers(1, 64), seed=st.integers(0, 2 ** 31 - 1))
     def test_hyp_seed_sampling_deterministic(n, seed):
-        a = [p["seed"] for p in _points(SeedSampling(Val("s"), n, seed=seed))]
-        b = [p["seed"] for p in _points(SeedSampling(Val("s"), n, seed=seed))]
+        a = [p["s"] for p in _points(SeedSampling(Val("s"), n, seed=seed))]
+        b = [p["s"] for p in _points(SeedSampling(Val("s"), n, seed=seed))]
         assert a == b and len(a) == n
 
     @needs_hypothesis

@@ -111,6 +111,22 @@ def test_ops_route_matches_ref_on_both_sides_of_the_gate():
             np.asarray(_jit_matrix_ref(x, x, "rbf", 0.4, 1.0)))
 
 
+def test_batched_assembly_equals_per_start_bitwise():
+    """The acquisition sweep assembles every multi-start batch's
+    cross-covariance against the archive in one vmapped pass; each slice
+    must be bitwise the unbatched per-start assembly."""
+    n, d, b, q = 512, 16, 16, 8
+    x = _xy(jax.random.key(0), n, d, scale=1.0)
+    xs = jax.random.uniform(jax.random.key(1), (b, q, d), jnp.float32)
+    batched = jax.jit(
+        lambda x, xs: jax.vmap(lambda s: kref.gp_matrix_ref(s, x))(xs))
+    per_start = jax.jit(lambda s, x: kref.gp_matrix_ref(s, x))
+    got = np.asarray(batched(x, xs))
+    for i in range(b):
+        np.testing.assert_array_equal(got[i],
+                                      np.asarray(per_start(xs[i], x)))
+
+
 def test_gp_matrix_symmetric_and_bounded():
     x = _xy(jax.random.key(5), 50, 4)
     for kind in ("matern52", "rbf"):

@@ -5,13 +5,10 @@ Listing 4 (NSGA-II calibration) -> Listing 5 (island distribution), all on
 the reduced ants config, plus packaging (CARE analogue) and the LM
 hyper-parameter exploration use case.
 """
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from repro.ants import simulate, simulate_batch
 from repro.configs.ants_netlogo import BOUNDS, REDUCED
@@ -127,56 +124,3 @@ def test_lm_hyperparameter_exploration_workflow():
     losses = {c["lr"]: c["final_loss"] for c in res[probe_c]}
     assert len(losses) == 2
     assert all(np.isfinite(v) for v in losses.values())
-
-
-_DRYRUN_DIR = os.path.join(os.path.dirname(__file__), "..",
-                           "experiments", "dryrun")
-
-
-def test_dryrun_artifact_audit_logic(tmp_path):
-    """The artifact auditor itself, on synthetic records: green sets pass,
-    and it pinpoints missing cells and failed cells. (Converted from the
-    old perma-skipped artifact gate — the audit logic now always runs;
-    the full-sweep gate below remains artifact-conditional.)"""
-    from repro.configs import all_cells
-    from repro.launch.dryrun import audit_dryrun_artifacts
-    cells = list(all_cells())[:4]
-    assert cells, "config registry must expose cells"
-    d = str(tmp_path)
-
-    def write(mesh, arch, shape, status):
-        with open(os.path.join(d, f"{mesh}__{arch}__{shape}.json"),
-                  "w") as f:
-            json.dump({"arch": arch, "shape": shape, "mesh": mesh,
-                       "status": status}, f)
-
-    for arch, _cfg, shape, status in cells:
-        write("pod", arch, shape.name, "ok" if status == "run" else status)
-    missing, bad = audit_dryrun_artifacts(d, meshes=("pod",), cells=cells)
-    assert missing == [] and bad == []
-
-    # a failed runnable cell is reported as bad
-    arch0, _c0, shape0, status0 = next(
-        c for c in cells if c[3] == "run")
-    write("pod", arch0, shape0.name, "FAILED rc=1")
-    missing, bad = audit_dryrun_artifacts(d, meshes=("pod",), cells=cells)
-    assert bad and bad[0][:3] == ("pod", arch0, shape0.name)
-
-    # a deleted record is reported as missing
-    os.remove(os.path.join(d, f"pod__{arch0}__{shape0.name}.json"))
-    missing, bad = audit_dryrun_artifacts(d, meshes=("pod",), cells=cells)
-    assert ("pod", arch0, shape0.name) in missing
-
-
-@pytest.mark.skipif(
-    not (os.path.isdir(_DRYRUN_DIR) and len(os.listdir(_DRYRUN_DIR)) >= 80),
-    reason="optional artifact gate: full dry-run sweep output absent "
-           "(generate with `python -m repro.launch.dryrun --all`, ~hours); "
-           "the audit logic itself is covered unconditionally above")
-def test_dryrun_artifacts_exist_and_green():
-    """The multi-pod dry-run must have produced a green record for every
-    runnable (arch x shape x mesh) cell."""
-    from repro.launch.dryrun import audit_dryrun_artifacts
-    missing, bad = audit_dryrun_artifacts(_DRYRUN_DIR)
-    assert not missing, f"missing dry-run cells: {missing[:5]}"
-    assert not bad, f"failed dry-run cells: {bad[:5]}"

@@ -3,7 +3,8 @@
 
 Scans README.md and docs/**/*.md for [text](target) links, skips absolute
 URLs and anchors, and fails if a relative target does not exist on disk.
-Run from the repo root (CI does):
+Run from the repo root (CI does; tests/test_docs_links.py runs the same
+check per file):
 
     python tools/check_docs_links.py
 """
@@ -25,14 +26,15 @@ def md_files(root):
                     yield os.path.join(dirpath, f)
 
 
-def check(root):
+def check_file(path):
+    """(where, target) for each relative link in ``path`` that resolves
+    to nothing on disk."""
+    if not os.path.exists(path):
+        return [(path, "<file missing>")]
     bad = []
-    for path in md_files(root):
-        if not os.path.exists(path):
-            bad.append((path, "<file missing>"))
-            continue
-        base = os.path.dirname(path)
-        for lineno, line in enumerate(open(path), 1):
+    base = os.path.dirname(path)
+    with open(path) as f:
+        for lineno, line in enumerate(f, 1):
             for target in LINK_RE.findall(line):
                 if target.startswith(SKIP_PREFIXES):
                     continue
@@ -43,6 +45,10 @@ def check(root):
                 if not os.path.exists(resolved):
                     bad.append((f"{path}:{lineno}", target))
     return bad
+
+
+def check(root):
+    return [b for path in md_files(root) for b in check_file(path)]
 
 
 if __name__ == "__main__":
